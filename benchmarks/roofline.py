@@ -1,102 +1,65 @@
-"""Roofline table from the dry-run artifacts (results/dryrun/*.json),
-plus an analytic roofline for the Pallas probe kernels.
-
-Per (arch x shape x mesh): the three terms in seconds, the dominant
-bottleneck, MODEL_FLOPS/HLO_FLOPs (useful-compute ratio), and the
-roofline fraction = (MODEL_FLOPS/chips/peak) / max(term) — the score a
-perfect-efficiency implementation would push to 1.0.
+"""Analytic roofline for the Pallas probe kernels, on the chip's peaks.
 
 :func:`kernel_table` covers the simulator's own kernels — the
 standalone ``ata_tag_probe`` *and* the fused ``ata_probe_rank``
-(probe + winner rank + port arbitration, PR 6) — with an analytic
-roofline derived from their BlockSpecs: HBM bytes actually streamed
-per grid step (the tag state is re-read once per request tile — that
-re-read, not the compare, is what bounds both kernels), integer VPU
-ops, arithmetic intensity, and the memory/compute-bound time on the
-reference chip. Wall time is measured only on a real TPU backend
-(``jax.default_backend() == "tpu"``); the interpret path on CPU
-validates semantics, not speed, so off-TPU rows report the model only.
+(probe + winner rank + port arbitration) — with an analytic roofline
+derived from their BlockSpecs: HBM bytes actually moved per call
+(the tag state's block index never changes, so it is read once per
+call; request columns and outputs stream once), integer VPU ops,
+arithmetic intensity, and the memory/compute-bound time on the device
+the run finds. Peaks come from :data:`PEAKS`, keyed by
+``jax.devices()[0].device_kind``; a device missing from the table is
+an error, never a default. Wall time is measured with the compiled
+kernel (wrapper and kernel jitted as one executable, as the simulator
+runs it), so the table needs a TPU.
 """
-import glob
-import json
-import pathlib
+import jax
 
-RESULTS = pathlib.Path(__file__).resolve().parents[1] / "results" / "dryrun"
-PEAK_FLOPS = 197e12
-HBM_BW = 1.2e12          # bytes/s, reference-chip HBM stream rate
-PEAK_INT_OPS = 4.9e13    # int32 VPU lanes (no MXU help for equality)
+#: Published per-chip peaks, keyed by ``device_kind``.
+#: Source: Google Cloud documentation, "TPU v5e" (system architecture):
+#: 819 GB/s HBM bandwidth, 197 TFLOP/s bf16, 393 TOP/s int8.
+PEAKS = {
+    "TPU v5 lite": {"hbm_bytes_per_s": 819e9, "bf16_flops": 197e12,
+                    "int8_ops": 393e12},
+}
 
 #: Canonical probe-kernel shape (matches benchmarks.kernel_micro):
 #: R requests against C caches of S sets x W ways, clusters of G.
 KERNEL_SHAPE = {"R": 1024, "C": 16, "S": 8, "W": 64, "G": 4}
 
 
-def load(mesh="sp"):
-    rows = []
-    for f in sorted(glob.glob(str(RESULTS / f"*__{mesh}.json"))):
-        r = json.load(open(f))
-        rows.append(r)
-    return rows
-
-
-def fraction(r):
-    if r["status"] != "ok":
-        return None
-    t = r["roofline"]
-    ideal = t["model_flops_global"] / r["chips"] / PEAK_FLOPS
-    bound = max(t["compute_s"], t["memory_s"], t["collective_s"])
-    return ideal / bound if bound else None
-
-
-def table(mesh="sp"):
-    rows = []
-    for r in load(mesh):
-        if r["status"] == "skipped":
-            rows.append((r["arch"], r["shape"], "SKIP", r.get("reason", "")))
-            continue
-        if r["status"] != "ok":
-            rows.append((r["arch"], r["shape"], "ERR", r.get("error", "")[:60]))
-            continue
-        t = r["roofline"]
-        frac = fraction(r)
-        rows.append((
-            r["arch"], r["shape"], t["dominant"],
-            f"{t['compute_s']:.4f}", f"{t['memory_s']:.4f}",
-            f"{t['collective_s']:.4f}",
-            f"{t['useful_flops_ratio']:.2f}" if t["useful_flops_ratio"] else "-",
-            f"{frac:.3f}" if frac else "-",
-            f"{r['memory']['peak_estimate_gb']:.1f}GB",
-        ))
-    return rows
+def device_peaks() -> dict:
+    """The :data:`PEAKS` row of the device this process runs on."""
+    kind = jax.devices()[0].device_kind
+    if kind not in PEAKS:
+        raise KeyError(f"no published peaks for device_kind {kind!r}; "
+                       f"known: {sorted(PEAKS)}")
+    return PEAKS[kind]
 
 
 def kernel_model(name, shape=None):
     """Analytic (bytes, int_ops) per call for a probe kernel.
 
     Traffic follows the kernel BlockSpecs, not the array sizes: both
-    kernels hold the tag state resident per program but the grid walks
-    request tiles, so tags/valid(/dirty) stream from HBM once per tile
-    — ``R/br`` times per call. Ops count the one-hot set gather
-    (2 ops per (request, cache, set, way) lane: select + max) plus the
-    comparator group and per-request reductions.
+    kernels map the (int32) tag state to one block whose index is the
+    same at every grid step, so Pallas copies it into VMEM once per
+    call, while request columns and outputs stream once per tile. Ops
+    count the set selector (2 selects per (request, cache, set, way):
+    tag + line state) plus the comparator group and per-request
+    reductions.
     """
     s = dict(KERNEL_SHAPE, **(shape or {}))
     R, C, S, W = s["R"], s["C"], s["S"], s["W"]
     state = C * S * W
     if name == "ata_tag_probe":
-        from repro.kernels.ata_tag_probe import DEFAULT_BC, DEFAULT_BR
-        br, bc = min(DEFAULT_BR, R), min(DEFAULT_BC, C)
-        tiles = (R // br) * (C // bc)
-        bytes_ = (tiles * (bc * S * W) * (4 + 1)   # tags + valid
-                  + (C // bc) * R * 8              # set_idx + qtag
-                  + R * C * 5)                     # hits + ways out
+        bytes_ = (state * (4 + 4)                  # tags + valid
+                  + R * 8                          # set_idx + qtag
+                  + R * C * 8)                     # hits + ways out
         ops = R * C * W * (2 * S + 3)
     elif name == "ata_probe_rank":
-        from repro.kernels.ata_probe_rank import DEFAULT_BR
-        br = min(DEFAULT_BR, R)
-        bytes_ = ((R // br) * state * (4 + 1 + 1)  # tags+valid+dirty
-                  + R * 19                         # 6 request vectors in
-                  + R * 14 + C * 4)                # 5 outputs + counts
+        bytes_ = (state * (4 + 4)                  # tags + line state
+                  + R * 24                         # 6 request columns in
+                  + R * 20 + C * 4)                # 5 outputs + counts
         # probe over the full cluster + winner one-hot rank + the
         # grid-carried port-arbitration prefix counts
         ops = R * C * W * (2 * S + 3) + R * C * (s["G"] + 6)
@@ -107,26 +70,30 @@ def kernel_model(name, shape=None):
 
 def kernel_table(shape=None):
     """Rows: (kernel, bytes, ops, intensity, mem_s, comp_s, bound,
-    measured_us or None)."""
-    import jax
-    on_tpu = jax.default_backend() == "tpu"
+    measured_us).
+
+    The compute bound divides by the published int8 peak, an upper
+    bound the kernels' int32 VPU compares cannot reach; the memory
+    bound uses the HBM peak.
+    """
+    peaks = device_peaks()
     rows = []
     for name in ("ata_tag_probe", "ata_probe_rank"):
         bytes_, ops = kernel_model(name, shape)
-        mem_s = bytes_ / HBM_BW
-        comp_s = ops / PEAK_INT_OPS
+        mem_s = bytes_ / peaks["hbm_bytes_per_s"]
+        comp_s = ops / peaks["int8_ops"]
         bound = "memory" if mem_s >= comp_s else "compute"
-        measured = _time_kernel(name, shape) if on_tpu else None
+        measured = _time_kernel(name, shape)
         rows.append((name, bytes_, ops, ops / bytes_, mem_s, comp_s,
                      bound, measured))
     return rows
 
 
 def _time_kernel(name, shape=None, iters=20):
-    """Median wall us/call of the compiled Pallas kernel (TPU only)."""
+    """Median wall us/call of the compiled Pallas kernel (TPU only),
+    wrapper and kernel timed as one jitted executable."""
     import time
 
-    import jax
     import jax.numpy as jnp
     import numpy as np
 
@@ -139,21 +106,25 @@ def _time_kernel(name, shape=None, iters=20):
     qtag = jnp.asarray(rng.integers(0, 4096, R), jnp.int32)
     set_idx = jnp.asarray(rng.integers(0, S, R), jnp.int32)
     if name == "ata_tag_probe":
-        call = lambda: ops.ata_probe(set_idx, qtag, tags, valid,  # noqa: E731
-                                     impl="pallas")
+        args = (set_idx, qtag, tags, valid)
+
+        def fn(*a):
+            return ops.ata_probe(*a, impl="pallas")
     else:
         core = jnp.asarray(rng.integers(0, C, R), jnp.int32)
         cbase = (core // G) * G
         deny = jnp.asarray(rng.random(R) < 0.2)
         dirty = jnp.asarray(valid & (rng.random((C, S, W)) < 0.2))
-        call = lambda: ops.ata_probe_rank(                        # noqa: E731
-            set_idx, qtag, core, cbase, deny, tags, valid, dirty,
-            cluster_size=G, impl="pallas")
-    jax.block_until_ready(call())
+        args = (set_idx, qtag, core, cbase, deny, tags, valid, dirty)
+
+        def fn(*a):
+            return ops.ata_probe_rank(*a, cluster_size=G, impl="pallas")
+    fn = jax.jit(fn)
+    jax.block_until_ready(fn(*args))
     times = []
     for _ in range(iters):
         t0 = time.perf_counter()
-        jax.block_until_ready(call())
+        jax.block_until_ready(fn(*args))
         times.append(time.perf_counter() - t0)
     return sorted(times)[len(times) // 2] * 1e6
 
@@ -165,25 +136,13 @@ def print_kernel_table(shape=None):
     print(f"{'kernel':16s} {'KB':>8s} {'ops':>10s} {'ops/B':>6s} "
           f"{'mem_us':>8s} {'comp_us':>8s} {'bound':8s} {'meas_us':>8s}")
     for name, b, o, ai, mem_s, comp_s, bound, meas in kernel_table(shape):
-        meas_col = f"{meas:>8.1f}" if meas is not None else f"{'-':>8s}"
         print(f"{name:16s} {b / 1024:>8.1f} {o:>10d} {ai:>6.1f} "
               f"{mem_s * 1e6:>8.2f} {comp_s * 1e6:>8.2f} {bound:8s} "
-              f"{meas_col}")
+              f"{meas:>8.1f}")
 
 
 def main():
     print_kernel_table()
-    for mesh, name in (("sp", "single-pod 16x16"), ("mp", "multi-pod 2x16x16")):
-        print(f"\n=== roofline: {name} ===")
-        print(f"{'arch':22s} {'shape':12s} {'bound':10s} {'comp_s':>8s} "
-              f"{'mem_s':>8s} {'coll_s':>8s} {'useful':>6s} {'frac':>6s} {'peak':>8s}")
-        for row in table(mesh):
-            if row[2] in ("SKIP", "ERR"):
-                print(f"{row[0]:22s} {row[1]:12s} {row[2]:10s} {row[3][:50]}")
-            else:
-                print(f"{row[0]:22s} {row[1]:12s} {row[2]:10s} "
-                      f"{row[3]:>8s} {row[4]:>8s} {row[5]:>8s} {row[6]:>6s} "
-                      f"{row[7]:>6s} {row[8]:>8s}")
 
 
 if __name__ == "__main__":

@@ -1,9 +1,13 @@
 """Benchmark suite: one module per paper table/figure + kernels +
-serving + roofline. Prints ``name,us_per_call,derived`` CSV.
+serving (+ the probe-kernel roofline on a TPU). Prints
+``name,us_per_call,derived`` CSV.
 
   PYTHONPATH=src python -m benchmarks.run [--full] [--rounds N] \
       [--report-json PATH] [--serving-json PATH] [--serving-rounds N] \
-      [--telemetry OUT_DIR]
+      [--telemetry OUT_DIR] [--roofline]
+
+JAX's persistent compilation cache is on (``repro.compile_cache``):
+``$JAX_COMPILATION_CACHE_DIR`` when set, else ``<repo>/.jax_cache``.
 
 Every figure is timed individually (``figure.<name>.wall_s`` lines)
 and run under a failure collector: a figure that raises prints its
@@ -40,6 +44,11 @@ per stream (CI smoke uses 512 to match
 and any ``--full`` run — calibrates rounds so every (shards, mix)
 stream replays at least 1,000,000 requests.
 
+--roofline runs the probe-kernel roofline (``benchmarks.roofline``):
+the compiled Pallas kernels timed against the published peaks of the
+device found. It needs a TPU listed in ``roofline.PEAKS``; anywhere
+else the phase fails, and with it the run.
+
 --full uses every per-app kernel (Fig. 9 fidelity); default trims for
 CI speed on the 1-core container. --rounds truncates every trace (CI
 smoke). The figure sweeps run through ``repro.core.sweep.SweepGrid`` —
@@ -51,6 +60,7 @@ simulations. The ``sweep.executables_compiled`` /
 in CI logs.
 """
 import argparse
+import os
 import sys
 import time
 import traceback
@@ -101,6 +111,9 @@ def main() -> None:
     ap.add_argument("--telemetry", default=None, metavar="OUT_DIR",
                     help="run the observability smoke capture and "
                     "write timelines/traces/manifest into OUT_DIR")
+    ap.add_argument("--roofline", action="store_true",
+                    help="time the compiled probe kernels against the "
+                    "device's published peaks (TPU only)")
     args = ap.parse_args()
     del _FAILURES[:]
     k = 0 if args.full else 1
@@ -108,6 +121,9 @@ def main() -> None:
 
     print("name,us_per_call,derived")
     import jax
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     from benchmarks import (fig8_ipc, fig9_kernels, fig10_latency,
                             fig_mix_fairness, fig_noc_topology,
                             fig_sweep_geometry, kernel_micro, serving_ata,
@@ -195,27 +211,15 @@ def main() -> None:
                   file=sys.stderr)
         _figure("telemetry_capture", _telemetry)
 
-    # roofline summary (reads dry-run artifacts if present)
-    try:
-        from benchmarks import roofline
-        rows = roofline.table("sp")
-        ok = [r for r in rows if r[2] not in ("SKIP", "ERR")]
-        for r in ok:
-            emit(f"roofline.{r[0]}.{r[1]}.fraction", 0.0, r[7])
-        emit("roofline.cells_ok", 0.0, len(ok))
-    except Exception as e:                      # noqa: BLE001
-        print(f"roofline.skipped,0,{e!r}", file=sys.stderr)
-
-    # probe-kernel roofline: analytic everywhere, measured on TPU
-    try:
-        from benchmarks import roofline
-        for name, _, _, ai, mem_s, comp_s, bound, meas in \
-                roofline.kernel_table():
-            emit(f"roofline.kernel.{name}", meas if meas is not None
-                 else 0.0, f"{bound};ai={ai:.1f};"
-                 f"mem={mem_s * 1e6:.2f}us;comp={comp_s * 1e6:.2f}us")
-    except Exception as e:                      # noqa: BLE001
-        print(f"roofline.kernel.skipped,0,{e!r}", file=sys.stderr)
+    if args.roofline:
+        def _roofline():
+            from benchmarks import roofline
+            for name, _, _, ai, mem_s, comp_s, bound, meas in \
+                    roofline.kernel_table():
+                emit(f"roofline.kernel.{name}", meas,
+                     f"{bound};ai={ai:.1f};"
+                     f"mem={mem_s * 1e6:.2f}us;comp={comp_s * 1e6:.2f}us")
+        _figure("roofline", _roofline)
 
     if _FAILURES:
         print(f"{len(_FAILURES)} figure(s) failed: "

@@ -29,10 +29,11 @@ switches between same-dataflow models inside one executable):
 ``pallas``
     The fused Pallas TPU kernel (``repro.kernels.ata_probe_rank``):
     the same chain in one VMEM-resident pass per request tile,
-    compiled by Mosaic. TPU only.
+    compiled by Mosaic. TPU only: asking for it on another backend
+    raises, it never falls back to the interpreter.
 ``pallas_interpret``
-    The same kernel body interpreted on CPU — the exact-equivalence
-    artifact tier-1 tests pin against ``lax``.
+    The same kernel body interpreted (any backend) — the
+    exact-equivalence artifact tier-1 tests pin against ``lax``.
 
 All four return identical integers/booleans (tier-1 tested), so every
 committed golden is backend-invariant.
@@ -58,6 +59,9 @@ def check_probe_backend(backend: str) -> None:
         raise ValueError(
             f"probe_backend must be one of {PROBE_BACKENDS}, "
             f"got {backend!r}")
+    if backend == "pallas":
+        from repro.kernels.ata_tag_probe import require_tpu
+        require_tpu("probe_backend='pallas'")
 
 
 class ProbeRank(NamedTuple):
@@ -114,7 +118,7 @@ def _lax_path(geom, l1: tagarray.TagState, reqs, pre_served,
 
 
 def _pallas_path(geom, l1: tagarray.TagState, reqs, pre_served,
-                 interpret: Optional[bool]) -> ProbeRank:
+                 interpret: bool) -> ProbeRank:
     from repro.kernels.ata_probe_rank import ata_probe_rank
     deny = reqs.is_write
     if pre_served is not None:

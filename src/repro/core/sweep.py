@@ -31,8 +31,8 @@ compiling as few executables as possible:
   fields (core/set/way counts) fix array shapes and group points.
 * **device sharding** — each execution bucket's stacked point axis is
   padded to the device count and sharded with
-  ``repro.sharding.compat.shard_map``, so an N-device host runs N grid
-  points at a time per dispatch.
+  ``repro.sharding.compat.shard_map_norep``, so an N-device host runs N
+  grid points at a time per dispatch.
 
 An executable is therefore keyed by (arch dataflow group, NoC model
 group, geometry structure, trace *kind* = shape + insn shape + app
@@ -67,7 +67,7 @@ from repro.core.telemetry import TelemetryConfig
 from repro.core.arch import get_arch, registered_archs
 from repro.core.noc import get_noc, registered_nocs
 from repro.core.probe import check_probe_backend
-from repro.sharding.compat import make_mesh_1d, shard_map, shard_map_norep
+from repro.sharding.compat import make_mesh_1d, shard_map_norep
 from jax.sharding import PartitionSpec as P
 
 
@@ -94,6 +94,8 @@ class SweepReport:
     ``n_executables`` counts the distinct compiled programs the run
     dispatched to; ``n_compiles`` counts how many of those were built
     fresh this run (the rest were warm in the process-wide cache).
+    ``n_devices`` counts the devices the buckets' outputs actually
+    spanned (read from their shardings, not from the request).
     """
     n_points: int
     n_executables: int
@@ -141,14 +143,12 @@ def _sharded_executable(group: Tuple[str, ...], nocs: Tuple[str, ...],
                                      n_apps, probe_backend,
                                      telemetry))(point_arrays)
 
-        # Pallas backends embed a pallas_call, which has no shard_map
-        # replication rule — disable the check for those buckets only
-        # (the device-sharded grid axis is fully partitioned anyway, so
-        # the check never had anything to prove here).
-        smap = (shard_map_norep if probe_backend.startswith("pallas")
-                else shard_map)
-        fn = jax.jit(smap(local_batch, mesh=mesh,
-                          in_specs=P("grid"), out_specs=P("grid")))
+        # The grid axis is fully partitioned (every point lives on one
+        # device), so the varying-axes check has nothing to prove here;
+        # it is off for every bucket.
+        fn = jax.jit(shard_map_norep(local_batch, mesh=mesh,
+                                     in_specs=P("grid"),
+                                     out_specs=P("grid")))
         _EXEC_MEMO[key] = fn
     return fn
 
@@ -344,13 +344,20 @@ class SweepGrid:
         (``repro.obs.SimTimeline``, aligned with :attr:`points`) and
         per-point results stay bit-equal to the default run. ``None``
         reuses exactly the pre-telemetry executables.
+
+        ``n_devices`` (default: every local device) must not exceed the
+        devices present; asking for more raises.
         """
         t0 = time.perf_counter()
         if telemetry is not None:
             for p in self.points:
                 telemetry.window_for(p.trace.addr.shape[0])
         avail = len(jax.devices())
-        D = max(1, min(n_devices or avail, avail))
+        D = avail if n_devices is None else n_devices
+        if not 1 <= D <= avail:
+            raise ValueError(
+                f"n_devices={n_devices} but this host has {avail} "
+                f"{jax.default_backend()} device(s)")
 
         # Dataflow groups, ordered by first appearance of each arch;
         # NoC stacking groups the same way.
@@ -385,6 +392,7 @@ class SweepGrid:
         timelines: Optional[list] = (
             [None] * len(self.points) if telemetry is not None else None)
         used_execs: set = set()
+        spanned: set = set()
         new_compiles = 0
         for (group, noc_group, structure, kind, backend), idxs \
                 in buckets.items():
@@ -421,9 +429,10 @@ class SweepGrid:
                 new_compiles += 1
             fn = _sharded_executable(group, noc_group, structure, D,
                                      n_apps, backend, telemetry)
-            stats = jax.device_get(
-                fn((addr, is_write, insn, core_app, scalars, policy_idx,
-                    noc_idx)))
+            out = fn((addr, is_write, insn, core_app, scalars, policy_idx,
+                      noc_idx))
+            spanned |= out["cycles"].sharding.device_set
+            stats = jax.device_get(out)
             snaps = stats.pop("timeline", None)
             for b, i in enumerate(idxs):
                 p = self.points[i]
@@ -443,7 +452,7 @@ class SweepGrid:
             n_points=len(self.points),
             n_executables=len(used_execs),
             n_compiles=new_compiles,
-            n_devices=D,
+            n_devices=len(spanned),
             wall_s=time.perf_counter() - t0,
         )
         return SweepRun(results=results, report=report,  # type: ignore

@@ -1,8 +1,8 @@
 """Public jit'd entry points for the kernel package.
 
 Each op dispatches between implementations:
-  "pallas"    — the Pallas TPU kernel (interpret=False; real hardware)
-  "interpret" — the same kernel body interpreted on CPU (validation)
+  "pallas"    — the Pallas TPU kernel compiled by Mosaic (TPU only)
+  "interpret" — the same kernel body interpreted (any backend)
   "ref"       — the pure-jnp oracle (always available, used for dry-run
                 lowering and as the XLA fast path on non-TPU backends)
 

@@ -37,7 +37,6 @@ from repro.configs.shapes import SHAPES, applicable
 from repro.launch import specs as SP
 from repro.launch.hlo_analysis import analyze_text
 from repro.launch.mesh import make_production_mesh
-from repro.sharding.compat import activate_mesh
 from repro.models import transformer as T
 from repro.optim.adamw import AdamWConfig
 from repro.sharding.rules import make_rules, rules_context
@@ -84,7 +83,7 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool, *,
                        profile=profile)
     t0 = time.time()
 
-    with rules_context(mesh, rules), activate_mesh(mesh):
+    with rules_context(mesh, rules), jax.set_mesh(mesh):
         if shape.kind == "train":
             state_shape = SP.abstract_train_state(cfg)
             st_sh = SP.train_state_shardings(state_shape, cfg, mesh, rules)

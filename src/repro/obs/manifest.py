@@ -3,10 +3,11 @@
 Every benchmark report (``sensitivity`` / ``simspeed`` / ``serving``
 and the telemetry capture) attaches a ``manifest`` block so a number
 in ``bench_history/`` can always be traced back to the code revision,
-jax version, backend, device topology, compile activity, and phase
-wall-clock that produced it. All probes are guarded — a missing git
-binary, a detached worktree, or an XLA backend without cost analysis
-degrade to ``None`` fields, never to a failed benchmark run.
+jax version, device (platform, kind and count as ``jax.devices()``
+reports them), compile activity, and phase wall-clock that produced
+it. A missing git binary or a detached worktree degrades to a ``None``
+sha, and an XLA backend without cost analysis to ``None`` costs; a
+failure to reach jax's devices fails the run.
 
 The regression gates (``repro.core.report.compare_*``) iterate only
 the baseline's sections, so adding ``manifest`` to reports is
@@ -62,36 +63,22 @@ class PhaseTimer:
 
 
 def _compile_counts() -> Dict[str, int]:
-    counts: Dict[str, int] = {}
-    try:
-        from repro.core import sweep
-        counts["sweep"] = sweep.compile_count()
-    except Exception:
-        pass
-    try:
-        from repro.serving import engine
-        counts["serving"] = engine.compile_count()
-    except Exception:
-        pass
-    return counts
+    from repro.core import sweep
+    from repro.serving import engine
+    return {"sweep": sweep.compile_count(),
+            "serving": engine.compile_count()}
 
 
 def serving_executable_costs() -> Dict[str, dict]:
     """XLA cost analysis (FLOPs / bytes accessed) per cached serving
     executable, keyed by a readable (policy, B, C, K) label."""
+    from repro.serving import engine
     costs: Dict[str, dict] = {}
-    try:
-        from repro.serving import engine
-        executables = engine._EXECUTABLES
-    except Exception:
-        return costs
-    for key, exe in executables.items():
+    for key, exe in engine._EXECUTABLES.items():
         policy, _cfg, B, C, K = key[0], key[1], key[2], key[3], key[4]
         label = f"{policy}/B{B}/C{C}/K{K}"
         try:
             ca = exe.cost_analysis()
-            if isinstance(ca, list):     # older jax returns [dict]
-                ca = ca[0] if ca else {}
             costs[label] = {
                 "flops": float(ca.get("flops", 0.0)),
                 "bytes_accessed": float(ca.get("bytes accessed", 0.0)),
@@ -104,6 +91,8 @@ def serving_executable_costs() -> Dict[str, dict]:
 def run_manifest(phases: Optional[Dict[str, float]] = None,
                  extra: Optional[dict] = None) -> dict:
     """The manifest block attached to benchmark reports."""
+    import jax
+    devices = jax.devices()
     manifest: dict = {
         "git_sha": git_sha(),
         "python": platform.python_version(),
@@ -111,16 +100,12 @@ def run_manifest(phases: Optional[Dict[str, float]] = None,
         "argv": list(sys.argv),
         "timestamp": time.strftime("%Y-%m-%dT%H:%M:%S%z"),
         "compile_counts": _compile_counts(),
+        "jax_version": jax.__version__,
+        "backend": jax.default_backend(),
+        "device_platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
     }
-    try:
-        import jax
-        manifest["jax_version"] = jax.__version__
-        manifest["backend"] = jax.default_backend()
-        manifest["device_count"] = jax.device_count()
-    except Exception:
-        manifest["jax_version"] = None
-        manifest["backend"] = None
-        manifest["device_count"] = None
     costs = serving_executable_costs()
     if costs:
         manifest["serving_executable_costs"] = costs

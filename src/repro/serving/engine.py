@@ -87,13 +87,14 @@ from repro.core.geometry import GpuGeometry
 from repro.core.noc import NocTraffic, get_noc, init_noc_state
 from repro.core.telemetry import (TelemetryConfig, hist_quantile,
                                   serving_hist_bins)
-from repro.kernels.ata_tag_probe import ata_tag_probe
+from repro.kernels.ata_tag_probe import ata_tag_probe, require_tpu
 
 SERVING_POLICIES = ("private", "broadcast", "ata")
 
 #: Directory-probe backends: fused XLA gather/compare (default), the
-#: ``ata_tag_probe`` Pallas kernel compiled by Mosaic (TPU), and the
-#: same kernel interpreted (validation off-TPU).
+#: ``ata_tag_probe`` Pallas kernel compiled by Mosaic (TPU only — asking
+#: for it elsewhere raises), and the same kernel interpreted (any
+#: backend).
 SERVING_PROBE_BACKENDS = ("lax", "pallas", "pallas_interpret")
 
 #: Sub-rounds per compiled chunk. Fixed so every replay of the same
@@ -134,6 +135,8 @@ class ServingConfig:
             raise ValueError(
                 f"probe_backend must be one of {SERVING_PROBE_BACKENDS},"
                 f" got {self.probe_backend!r}")
+        if self.probe_backend == "pallas":
+            require_tpu("ServingConfig(probe_backend='pallas')")
 
     def geometry(self, n_shards: int) -> GpuGeometry:
         """The one-cluster geometry the NoC models price traffic with."""
@@ -228,10 +231,9 @@ def _probe_all(tags, h, set_idx, *, backend):
         hits = ((g_t == h[None, :, :, None]) & (g_t != 0)).any(-1)
         return jnp.transpose(hits, (1, 2, 0))       # (C, K, C_dir)
     R = C * K
-    bc = 8 if C % 8 == 0 else C
     hits, _ = ata_tag_probe(
-        set_idx.reshape(R), h.reshape(R), tags, tags != 0, br=R, bc=bc,
-        interpret=True if backend == "pallas_interpret" else None)
+        set_idx.reshape(R), h.reshape(R), tags, tags != 0, br=R,
+        interpret=backend == "pallas_interpret")
     return hits.reshape(C, K, C)
 
 

@@ -1,79 +1,41 @@
-"""Version compatibility shims for the moving ``jax.sharding`` surface.
+"""Mesh and ``shard_map`` helpers in the installed jax's spellings.
 
-The repo targets both older jax (0.4.3x: no ``jax.sharding.AxisType``,
-no ``jax.set_mesh``, ``shard_map`` still under ``jax.experimental``) and
-newer releases where those are the blessed spellings. Everything that
-touches mesh construction or global-mesh activation goes through here so
-tests and launch scripts run unchanged on either.
+The repo targets jax >= 0.9 (``jax.shard_map`` with ``check_vma``,
+``jax.sharding.AxisType``). Mesh construction goes through here so
+every caller builds meshes with the same axis types.
 """
 from __future__ import annotations
 
-import contextlib
 from typing import Sequence
 
 import jax
 import numpy as np
 
-try:  # jax >= 0.5-ish
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
-#: Whether this jax has explicit axis types on meshes.
-HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
+_AUTO = jax.sharding.AxisType.Auto
 
 
 def shard_map_norep(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` with the replication check disabled.
+    """``jax.shard_map`` with the varying-manual-axes check disabled.
 
-    Required when the mapped body contains ops without a replication
-    rule — ``pallas_call`` is the one in this repo (the fused-probe
-    simulator backends). The flag's spelling has moved across jax
-    releases (``check_rep`` -> ``check_vma``), so resolve it here.
+    Required when the mapped body carries state through ``lax.scan``
+    that the check cannot type (the simulator's round carry) or
+    contains a ``pallas_call``, which has no replication rule.
     """
-    import inspect
-    params = inspect.signature(shard_map).parameters
-    for kw in ("check_rep", "check_vma"):
-        if kw in params:
-            return shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **{kw: False})
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if HAS_AXIS_TYPES:
-        axis_types = (jax.sharding.AxisType.Auto,) * len(axis_names)
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                             axis_types=axis_types)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(_AUTO,) * len(axis_names))
 
 
 def make_mesh_1d(n_devices: int, axis_name: str):
     """A 1-D mesh over the first ``n_devices`` local devices.
 
-    Unlike :func:`make_mesh` / ``jax.make_mesh`` this slices the device
-    list explicitly, so sweeps can shard over a subset of the host's
-    devices (``jax.make_mesh`` insists on consuming a specific count in
-    some versions and reorders devices in others).
+    Unlike :func:`make_mesh` this slices the device list explicitly, so
+    sweeps can shard over a subset of the host's devices.
     """
     devs = np.asarray(jax.devices()[:n_devices])
-    if HAS_AXIS_TYPES:
-        return jax.sharding.Mesh(
-            devs, (axis_name,),
-            axis_types=(jax.sharding.AxisType.Auto,))
-    return jax.sharding.Mesh(devs, (axis_name,))
-
-
-def activate_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` (new) -> ``jax.sharding.use_mesh`` (mid) -> no-op
-    (old jax, where explicit NamedShardings on every jit boundary carry
-    the mesh and no ambient mesh exists).
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return contextlib.nullcontext()
+    return jax.sharding.Mesh(devs, (axis_name,), axis_types=(_AUTO,))

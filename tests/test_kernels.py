@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-from repro.kernels.ata_tag_probe import ata_tag_probe, default_interpret
+from repro.kernels.ata_tag_probe import ata_tag_probe
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.wkv6 import wkv6
 
@@ -19,18 +19,19 @@ def randn(*shape, dtype=jnp.float32, scale=1.0):
 # ---------------------------------------------------------------------------
 # ata_tag_probe
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("R,C,S,W,br,bc", [
-    (128, 8, 8, 64, 64, 4),
-    (256, 16, 8, 64, 128, 8),
-    (64, 4, 16, 8, 64, 4),
-    (32, 2, 2, 4, 32, 2),
+@pytest.mark.parametrize("R,C,S,W,br", [
+    (128, 8, 8, 64, 64),
+    (256, 16, 8, 64, 128),
+    (64, 30, 16, 8, 32),
+    (32, 2, 2, 4, 8),
 ])
-def test_ata_tag_probe_sweep(R, C, S, W, br, bc):
+def test_ata_tag_probe_sweep(R, C, S, W, br):
     tags = jnp.asarray(RNG.integers(0, 4096, (C, S, W)), jnp.int32)
     valid = jnp.asarray(RNG.random((C, S, W)) < 0.7)
     qtag = jnp.asarray(RNG.integers(0, 4096, R), jnp.int32)
     set_idx = jnp.asarray(RNG.integers(0, S, R), jnp.int32)
-    h1, w1 = ata_tag_probe(set_idx, qtag, tags, valid, br=br, bc=bc)
+    h1, w1 = ata_tag_probe(set_idx, qtag, tags, valid, br=br,
+                           interpret=True)
     h2, w2 = ref.ata_tag_probe_ref(set_idx, qtag, tags, valid)
     np.testing.assert_array_equal(np.asarray(h1), np.asarray(h2))
     np.testing.assert_array_equal(
@@ -46,24 +47,33 @@ def test_ata_tag_probe_planted_hits():
     set_idx = jnp.asarray(RNG.integers(0, S, R), jnp.int32)
     tags = tags.at[2, set_idx[5], 3].set(qtag[5])
     valid = valid.at[2, set_idx[5], 3].set(True)
-    hits, ways = ata_tag_probe(set_idx, qtag, tags, valid, br=32, bc=2)
+    hits, ways = ata_tag_probe(set_idx, qtag, tags, valid, br=32,
+                               interpret=True)
     assert bool(hits[5, 2]) and int(ways[5, 2]) == 3
     assert int(hits.sum()) >= 1
 
 
-def test_ata_tag_probe_interpret_autodetect():
-    """interpret=None resolves per platform *outside* the jit boundary:
-    on this CPU container it must pick the interpreter (and work)."""
-    assert default_interpret() is (jax.default_backend() != "tpu")
+@pytest.mark.parametrize("via_ops", [True, False],
+                         ids=["impl_pallas", "kernel_default"])
+def test_compiled_kernels_raise_off_tpu(via_ops):
+    """The compiled Pallas kernels never fall back to the interpreter:
+    off-TPU, asking for them (explicitly, or by the default) raises."""
+    assert jax.default_backend() != "tpu"
     C, S, W, R = 2, 4, 8, 32
     tags = jnp.asarray(RNG.integers(0, 64, (C, S, W)), jnp.int32)
     valid = jnp.asarray(RNG.random((C, S, W)) < 0.7)
     qtag = jnp.asarray(RNG.integers(0, 64, R), jnp.int32)
     set_idx = jnp.asarray(RNG.integers(0, S, R), jnp.int32)
-    h_auto, _ = ata_tag_probe(set_idx, qtag, tags, valid)
-    h_exp, _ = ata_tag_probe(set_idx, qtag, tags, valid,
-                             interpret=default_interpret())
-    np.testing.assert_array_equal(np.asarray(h_auto), np.asarray(h_exp))
+    core = jnp.zeros((R,), jnp.int32)
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        if via_ops:
+            ops.ata_probe(set_idx, qtag, tags, valid, impl="pallas")
+        else:
+            ata_tag_probe(set_idx, qtag, tags, valid)
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        ops.ata_probe_rank(set_idx, qtag, core, core, core > 0, tags,
+                           valid, valid, cluster_size=C,
+                           impl="pallas")
 
 
 # ---------------------------------------------------------------------------

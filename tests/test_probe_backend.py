@@ -283,6 +283,18 @@ def test_sim_speed_benchmark_reports_and_self_gates(tmp_path):
     assert compare_simspeed(on_disk, rep) == []
 
 
+def test_compiled_pallas_backend_raises_off_tpu():
+    """``pallas`` is the Mosaic-compiled kernel only: off-TPU, asking
+    for it raises instead of falling back to the interpreter."""
+    assert jax.default_backend() != "tpu"
+    tr = make_trace(_small_app("cfd"))
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        simulate("ata", tr, probe_backend="pallas")
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        SweepGrid(["ata"], [PAPER_GEOMETRY], [tr],
+                  probe_backends=["pallas"])
+
+
 def test_non_ata_archs_ignore_backend():
     """The axis is ATA-family-only: other policies accept and ignore
     it, so one grid can mix families without a signature split."""

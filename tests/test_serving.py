@@ -88,5 +88,5 @@ def test_kernel_backed_directory_probe_agrees():
     hits, _ = ops.ata_probe(jnp.asarray(set_idx), jnp.asarray(h32),
                             jnp.asarray(tags32),
                             jnp.asarray(cache.valid), impl="interpret",
-                            br=64, bc=4)
+                            br=32)
     np.testing.assert_array_equal(np.asarray(hits).any(axis=1), hit_ref)

@@ -240,6 +240,16 @@ def test_pallas_interpret_backend_matches_lax(stream, results):
     np.testing.assert_array_equal(res.latency, results["ata"].latency)
 
 
+def test_compiled_pallas_backend_raises_off_tpu():
+    """``pallas`` means the Mosaic-compiled kernel: off-TPU the config
+    is refused instead of silently interpreting."""
+    import jax
+    assert jax.default_backend() != "tpu"
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        ServingConfig(probe_backend="pallas")
+    ServingConfig(probe_backend="pallas_interpret")
+
+
 def test_bad_probe_backend_rejected():
     with pytest.raises(ValueError):
         ServingConfig(probe_backend="mosaic?")

@@ -32,6 +32,7 @@ def test_param_axes_cover_every_full_config_param(arch):
 
 def _run_subprocess(code: str):
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     return subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
@@ -46,13 +47,12 @@ def test_sharded_train_step_runs_on_8_devices():
         from repro.launch import specs as SP
         from repro.launch.mesh import make_test_mesh
         from repro.optim.adamw import AdamWConfig
-        from repro.sharding.compat import activate_mesh
         from repro.sharding.rules import make_rules, rules_context
         from repro.train.step import init_train_state, make_train_step
         cfg = get_smoke_config("qwen3-0.6b")
         mesh = make_test_mesh(4, 2)
         rules = make_rules(cfg, mesh, batch_size=8)
-        with rules_context(mesh, rules), activate_mesh(mesh):
+        with rules_context(mesh, rules), jax.set_mesh(mesh):
             state = init_train_state(jax.random.PRNGKey(0), cfg)
             st_sh = SP.train_state_shardings(
                 jax.eval_shape(lambda: state), cfg, mesh, rules)
@@ -77,7 +77,6 @@ def test_dp_profile_matches_tp_profile_loss():
         from repro.launch import specs as SP
         from repro.launch.mesh import make_test_mesh
         from repro.optim.adamw import AdamWConfig
-        from repro.sharding.compat import activate_mesh
         from repro.sharding.rules import make_rules, rules_context
         from repro.train.step import init_train_state, make_train_step
         cfg = get_smoke_config("qwen3-0.6b")
@@ -85,7 +84,7 @@ def test_dp_profile_matches_tp_profile_loss():
         losses = []
         for profile in ("tp", "dp"):
             rules = make_rules(cfg, mesh, batch_size=8, profile=profile)
-            with rules_context(mesh, rules), activate_mesh(mesh):
+            with rules_context(mesh, rules), jax.set_mesh(mesh):
                 state = init_train_state(jax.random.PRNGKey(0), cfg)
                 st_sh = SP.train_state_shardings(
                     jax.eval_shape(lambda: state), cfg, mesh, rules)
@@ -111,7 +110,8 @@ def test_compressed_allreduce_on_8_devices():
         from functools import partial
         from jax.sharding import PartitionSpec as P
         from repro.optim.compression import all_reduce_compressed
-        from repro.sharding.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.sharding.compat import make_mesh
         mesh = make_mesh((8,), ("data",))
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
         e = jnp.zeros((8, 64))

@@ -127,6 +127,7 @@ def test_run_suite_matches_per_point_simulate():
 # ---------------------------------------------------------------------------
 def test_sharded_sweep_on_8_devices_bit_identical():
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     r = subprocess.run([sys.executable, "-c", textwrap.dedent("""
@@ -150,6 +151,16 @@ def test_sharded_sweep_on_8_devices_bit_identical():
         print("SHARDED_SWEEP_OK", run.report.n_points)
     """)], capture_output=True, text=True, env=env, timeout=900)
     assert "SHARDED_SWEEP_OK" in r.stdout, r.stdout + r.stderr
+
+
+def test_sweep_run_rejects_more_devices_than_present():
+    """A requested device count is never clamped to what exists."""
+    import jax
+    grid = SweepGrid(("ata",), None, _traces("cfd", kernels=1))
+    with pytest.raises(ValueError, match="n_devices"):
+        grid.run(n_devices=len(jax.devices()) + 1)
+    with pytest.raises(ValueError, match="n_devices"):
+        grid.run(n_devices=0)
 
 
 # ---------------------------------------------------------------------------

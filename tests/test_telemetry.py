@@ -248,6 +248,10 @@ def test_run_manifest_shape():
     m = run_manifest(phases={"x": 1.25}, extra={"note": "t"})
     assert isinstance(m["git_sha"], str) and len(m["git_sha"]) == 40
     assert m["jax_version"] and m["backend"]
+    import jax
+    dev = jax.devices()[0]
+    assert (m["device_platform"], m["device_kind"], m["device_count"]) \
+        == (dev.platform, dev.device_kind, len(jax.devices()))
     assert m["phases_wall_s"] == {"x": 1.25}
     assert m["note"] == "t"
     assert "sweep" in m["compile_counts"]
