@@ -1,0 +1,2 @@
+"""Plain numpy references that decide ``correct``; they import nothing
+of the program."""
