@@ -37,11 +37,18 @@ switches between same-dataflow models inside one executable):
 
 All four return identical integers/booleans (tier-1 tested), so every
 committed golden is backend-invariant.
+
+Every backend runs inside the ``jax.named_scope`` :data:`PROBE_SCOPE`
+(nested in the round's ``l1`` stage scope), and the Pallas kernel
+is named ``ata_probe_rank``, so the probe's device time can be told
+apart from the rest of the round. The scope's name is an interface
+the benchmark reads.
 """
 from __future__ import annotations
 
 from typing import NamedTuple, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import tagarray
@@ -52,6 +59,8 @@ from repro.core.tagarray import ReplacementPolicy
 PROBE_BACKENDS: Tuple[str, ...] = ("lax", "lax_unfused", "pallas",
                                    "pallas_interpret")
 DEFAULT_PROBE_BACKEND = "lax"
+#: Named scope of the whole probe chain, under every backend.
+PROBE_SCOPE = "probe"
 
 
 def check_probe_backend(backend: str) -> None:
@@ -145,9 +154,11 @@ def fused_probe_rank(geom, l1: tagarray.TagState, reqs, *,
     ``lax_unfused``, which reproduces the historical victim probe.
     """
     check_probe_backend(backend)
-    if backend == "lax":
-        return _lax_path(geom, l1, reqs, pre_served, replacement, True)
-    if backend == "lax_unfused":
-        return _lax_path(geom, l1, reqs, pre_served, replacement, False)
-    return _pallas_path(geom, l1, reqs, pre_served,
-                        interpret=(backend == "pallas_interpret"))
+    with jax.named_scope(PROBE_SCOPE):
+        if backend == "lax":
+            return _lax_path(geom, l1, reqs, pre_served, replacement, True)
+        if backend == "lax_unfused":
+            return _lax_path(geom, l1, reqs, pre_served, replacement,
+                             False)
+        return _pallas_path(geom, l1, reqs, pre_served,
+                            interpret=(backend == "pallas_interpret"))

@@ -80,6 +80,14 @@ from repro.core.telemetry import TelemetryConfig, log2_bucket
 #: extensible set is ``repro.core.arch.registered_archs()``.
 ARCHITECTURES = PAPER_ARCHITECTURES
 
+#: The round's stages, in pipeline order: each is one ``jax.named_scope``
+#: in :func:`_round`, so every operation a stage lowers to carries
+#: ``.../<stage>/...`` in its HLO ``op_name`` metadata (the aggregated
+#: probe nests ``repro.core.probe.PROBE_SCOPE`` inside ``l1``). These
+#: names are an interface: the benchmark attributes device time to them.
+ROUND_STAGES: Tuple[str, ...] = ("l1", "l2", "fill", "noc", "timing")
+_L1, _L2, _FILL, _NOC, _TIMING = ROUND_STAGES
+
 
 class _TraceBase(NamedTuple):
     addr: np.ndarray       # (T, C, m) int32 line addresses
@@ -358,6 +366,9 @@ def _round(policy: ArchPolicy, nocs: Sequence[NocModel], noc_idx,
     has more than one member). ``probe_backend`` selects the L1 probe
     lowering (``repro.core.probe``) — *static*, since the backends
     lower structurally different programs; every backend is bit-exact.
+
+    Each stage runs under its :data:`ROUND_STAGES` named scope; a scope
+    changes only op metadata, never the computation.
     """
     l1, l2, noc, t, stats = state
     addr, is_write = xs                      # (C, m)
@@ -367,36 +378,39 @@ def _round(policy: ArchPolicy, nocs: Sequence[NocModel], noc_idx,
     R = reqs.n_requests
 
     # ---- L1 policy stage (the only architecture-specific part) ------------
-    out = policy.l1_stage(geom, l1, reqs, t, backend=probe_backend)
-    l1 = out.l1
-    go_l2 = out.go_l2
-    noc_flits = jnp.asarray(out.noc_flits, jnp.float32)
-    occupancy = out.occupancy
+    with jax.named_scope(_L1):
+        out = policy.l1_stage(geom, l1, reqs, t, backend=probe_backend)
+        l1 = out.l1
+        go_l2 = out.go_l2
+        noc_flits = jnp.asarray(out.noc_flits, jnp.float32)
+        occupancy = out.occupancy
 
     # ---- L2 stage ---------------------------------------------------------
-    l2_part = (addr % geom.l2_parts).astype(jnp.int32)
-    l2_set = ((addr // geom.l2_parts) % geom.l2_sets).astype(jnp.int32)
-    l2_hit, l2_way, _ = tagarray.probe(l2, l2_part, l2_set, addr)
-    l2_rank, l2_size = group_rank(l2_part, go_l2, geom.l2_parts)
-    l2_time = (geom.lat_l2 + l2_rank.astype(jnp.float32) * geom.svc_l2
-               + jnp.where(l2_hit, 0.0, geom.lat_dram * 1.0))
-    occupancy = jnp.maximum(
-        occupancy,
-        jnp.where(go_l2, l2_size.astype(jnp.float32) * geom.svc_l2, 0.0))
-    l2 = tagarray.touch(l2, l2_part, l2_set, l2_way, t, go_l2 & l2_hit)
-    l2, _ = tagarray.fill(l2, l2_part, l2_set, l2_way, addr, t,
-                          go_l2 & ~l2_hit)
-    noc_flits = noc_flits + jnp.sum(go_l2) * geom.flits_per_line
+    with jax.named_scope(_L2):
+        l2_part = (addr % geom.l2_parts).astype(jnp.int32)
+        l2_set = ((addr // geom.l2_parts) % geom.l2_sets).astype(jnp.int32)
+        l2_hit, l2_way, _ = tagarray.probe(l2, l2_part, l2_set, addr)
+        l2_rank, l2_size = group_rank(l2_part, go_l2, geom.l2_parts)
+        l2_time = (geom.lat_l2 + l2_rank.astype(jnp.float32) * geom.svc_l2
+                   + jnp.where(l2_hit, 0.0, geom.lat_dram * 1.0))
+        occupancy = jnp.maximum(
+            occupancy,
+            jnp.where(go_l2, l2_size.astype(jnp.float32) * geom.svc_l2, 0.0))
+        l2 = tagarray.touch(l2, l2_part, l2_set, l2_way, t, go_l2 & l2_hit)
+        l2, _ = tagarray.fill(l2, l2_part, l2_set, l2_way, addr, t,
+                              go_l2 & ~l2_hit)
+        noc_flits = noc_flits + jnp.sum(go_l2) * geom.flits_per_line
 
     # ---- L1 fill on L2 return (and on remote fetch: replicate locally) ----
-    fill_mask = go_l2 | out.remote_hits
-    if out.bypass_fill is not None:
-        fill_mask = fill_mask & ~out.bypass_fill
-    _, fway, _ = tagarray.probe(l1, out.fill_cache, out.fill_set, addr,
-                                policy=policy.replacement)
-    l1, wb = tagarray.fill(l1, out.fill_cache, out.fill_set, fway, addr, t,
-                           fill_mask, dirty=reqs.is_write)
-    noc_flits = noc_flits + jnp.sum(wb) * geom.flits_per_line
+    with jax.named_scope(_FILL):
+        fill_mask = go_l2 | out.remote_hits
+        if out.bypass_fill is not None:
+            fill_mask = fill_mask & ~out.bypass_fill
+        _, fway, _ = tagarray.probe(l1, out.fill_cache, out.fill_set, addr,
+                                    policy=policy.replacement)
+        l1, wb = tagarray.fill(l1, out.fill_cache, out.fill_set, fway, addr, t,
+                               fill_mask, dirty=reqs.is_write)
+        noc_flits = noc_flits + jnp.sum(wb) * geom.flits_per_line
 
     # ---- NoC stage: remote-probe/remote-data flits through the active
     # interconnect model (repro.core.noc). The policies' own memoryless
@@ -404,74 +418,76 @@ def _round(policy: ArchPolicy, nocs: Sequence[NocModel], noc_idx,
     # cross-round queue backpressure, hop latency, link hotspots — and
     # the `ideal` model adds exactly zero (bit-exact with the pre-NoC
     # simulator).
-    req_flits = out.noc_req_flits
-    if req_flits is None:
-        req_flits = out.remote_hits * (geom.flits_per_line * 1.0)
-    req_flits = jnp.asarray(req_flits, jnp.float32)
-    traffic = NocTraffic(
-        src=out.noc_src if out.noc_src is not None else reqs.core,
-        dst=reqs.core, cluster=reqs.cluster, flits=req_flits,
-        mask=req_flits > 0)
-    if len(nocs) == 1:
-        transit = nocs[0].transit(geom, noc, traffic)
-    else:
-        transit = jax.lax.switch(
-            noc_idx, [functools.partial(m.transit, geom) for m in nocs],
-            noc, traffic)
-    noc = transit.state
-    occupancy = jnp.maximum(occupancy, transit.occupancy)
+    with jax.named_scope(_NOC):
+        req_flits = out.noc_req_flits
+        if req_flits is None:
+            req_flits = out.remote_hits * (geom.flits_per_line * 1.0)
+        req_flits = jnp.asarray(req_flits, jnp.float32)
+        traffic = NocTraffic(
+            src=out.noc_src if out.noc_src is not None else reqs.core,
+            dst=reqs.core, cluster=reqs.cluster, flits=req_flits,
+            mask=req_flits > 0)
+        if len(nocs) == 1:
+            transit = nocs[0].transit(geom, noc, traffic)
+        else:
+            transit = jax.lax.switch(
+                noc_idx, [functools.partial(m.transit, geom) for m in nocs],
+                noc, traffic)
+        noc = transit.state
+        occupancy = jnp.maximum(occupancy, transit.occupancy)
 
     # ---- timing ------------------------------------------------------------
-    latency = (jnp.where(out.served, out.l1_time, out.pre_l2 + l2_time)
-               + transit.delay)                                     # (R,)
-    # Warp multithreading hides individual request latencies; the core's
-    # sustained pace is set by *mean* outstanding latency per load, while
-    # serial-resource occupancy is a hard throughput bound (max over m).
-    per_core_lat = latency.reshape(C, m).mean(axis=1)
-    per_core_occ = occupancy.reshape(C, m).max(axis=1)
-    pace = m * insn_per_req / geom.issue_rate
-    round_cost = jnp.maximum(jnp.maximum(pace, per_core_occ),
-                             per_core_lat / geom.hide)         # (C,)
+    with jax.named_scope(_TIMING):
+        latency = (jnp.where(out.served, out.l1_time, out.pre_l2 + l2_time)
+                   + transit.delay)                                     # (R,)
+        # Warp multithreading hides individual request latencies; the core's
+        # sustained pace is set by *mean* outstanding latency per load, while
+        # serial-resource occupancy is a hard throughput bound (max over m).
+        per_core_lat = latency.reshape(C, m).mean(axis=1)
+        per_core_occ = occupancy.reshape(C, m).max(axis=1)
+        pace = m * insn_per_req / geom.issue_rate
+        round_cost = jnp.maximum(jnp.maximum(pace, per_core_occ),
+                                 per_core_lat / geom.hide)         # (C,)
 
-    # Fig.10 metric: completion time of the L1 accesses of one load
-    # instruction, over loads fully served by the L1 complex. The NoC
-    # transit delay of a remote hit is part of that completion time
-    # (exactly 0.0 under `ideal`, so the golden pins are unaffected).
-    all_served = out.served.reshape(C, m).all(axis=1)
-    l1_complete = (out.l1_time + transit.delay).reshape(C, m).max(axis=1)
+        # Fig.10 metric: completion time of the L1 accesses of one load
+        # instruction, over loads fully served by the L1 complex. The NoC
+        # transit delay of a remote hit is part of that completion time
+        # (exactly 0.0 under `ideal`, so the golden pins are unaffected).
+        all_served = out.served.reshape(C, m).all(axis=1)
+        l1_complete = (out.l1_time + transit.delay).reshape(C, m).max(axis=1)
 
-    # Per-app attribution: hit counters scatter-add by the issuing
-    # core's app id inside the existing carry (hit counts are small
-    # integers in float32 — exact regardless of accumulation order).
-    req_app = core_app[reqs.core]                               # (R,)
-    f32 = jnp.float32
-    app_served_lat = jnp.where(all_served, l1_complete, 0.0)    # (C,)
+        # Per-app attribution: hit counters scatter-add by the issuing
+        # core's app id inside the existing carry (hit counts are small
+        # integers in float32 — exact regardless of accumulation order).
+        req_app = core_app[reqs.core]                               # (R,)
+        f32 = jnp.float32
+        app_served_lat = jnp.where(all_served, l1_complete, 0.0)    # (C,)
 
-    stats = {
-        "cycles": stats["cycles"] + round_cost,
-        "l1_lat_sum": stats["l1_lat_sum"] + jnp.sum(app_served_lat),
-        "l1_lat_n": stats["l1_lat_n"] + jnp.sum(all_served),
-        "local_hits": stats["local_hits"] + jnp.sum(out.local_hits),
-        "remote_hits": stats["remote_hits"] + jnp.sum(out.remote_hits),
-        "requests": stats["requests"] + R,
-        "l2_accesses": stats["l2_accesses"] + jnp.sum(go_l2),
-        "dram": stats["dram"] + jnp.sum(go_l2 & ~l2_hit),
-        "noc_flits": stats["noc_flits"] + noc_flits,
-        "app_local": stats["app_local"]
-        .at[req_app].add(out.local_hits.astype(f32)),
-        "app_remote": stats["app_remote"]
-        .at[req_app].add(out.remote_hits.astype(f32)),
-        "app_lat_sum": stats["app_lat_sum"]
-        .at[core_app].add(app_served_lat),
-        "app_lat_n": stats["app_lat_n"]
-        .at[core_app].add(all_served.astype(f32)),
-    }
-    if telemetry is not None and telemetry.histograms:
-        # log2-bucketed L1-complete latency histogram over served
-        # loads (unserved cores contribute an add of 0 — a no-op).
-        bucket = log2_bucket(l1_complete, telemetry.sim_hist_bins)
-        stats["lat_hist"] = state[4]["lat_hist"] \
-            .at[bucket].add(all_served.astype(jnp.int32))
+        stats = {
+            "cycles": stats["cycles"] + round_cost,
+            "l1_lat_sum": stats["l1_lat_sum"] + jnp.sum(app_served_lat),
+            "l1_lat_n": stats["l1_lat_n"] + jnp.sum(all_served),
+            "local_hits": stats["local_hits"] + jnp.sum(out.local_hits),
+            "remote_hits": stats["remote_hits"] + jnp.sum(out.remote_hits),
+            "requests": stats["requests"] + R,
+            "l2_accesses": stats["l2_accesses"] + jnp.sum(go_l2),
+            "dram": stats["dram"] + jnp.sum(go_l2 & ~l2_hit),
+            "noc_flits": stats["noc_flits"] + noc_flits,
+            "app_local": stats["app_local"]
+            .at[req_app].add(out.local_hits.astype(f32)),
+            "app_remote": stats["app_remote"]
+            .at[req_app].add(out.remote_hits.astype(f32)),
+            "app_lat_sum": stats["app_lat_sum"]
+            .at[core_app].add(app_served_lat),
+            "app_lat_n": stats["app_lat_n"]
+            .at[core_app].add(all_served.astype(f32)),
+        }
+        if telemetry is not None and telemetry.histograms:
+            # log2-bucketed L1-complete latency histogram over served
+            # loads (unserved cores contribute an add of 0 — a no-op).
+            bucket = log2_bucket(l1_complete, telemetry.sim_hist_bins)
+            stats["lat_hist"] = state[4]["lat_hist"] \
+                .at[bucket].add(all_served.astype(jnp.int32))
     return (l1, l2, noc, t + 1, stats), None
 
 

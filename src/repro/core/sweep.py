@@ -47,6 +47,16 @@ grid points: same-shape mixes share one executable per dataflow group.
 Results are bit-identical to running :func:`repro.core.simulate`
 per point (a tier-1 test asserts this), so figures can move freely
 between the two.
+
+:meth:`SweepGrid.run` marks its host steps with profiler spans
+(``jax.profiler.TraceAnnotation``, :data:`RUN_SPANS`): ``sweep.run``
+around the call, ``sweep.prepare`` inside it, then per bucket
+``sweep.inputs``, ``sweep.launch``, ``sweep.fetch`` and
+``sweep.summarize``. With no profiler running they cost a few tens of
+nanoseconds each. :func:`compiled_hlo` gives the optimised HLO of
+every executable the process dispatched, whose ``op_name`` metadata
+carries the round's stage scopes (``repro.core.simulator.ROUND_STAGES``).
+The span and scope names are an interface the benchmark reads.
 """
 from __future__ import annotations
 
@@ -112,9 +122,17 @@ class SweepRun(NamedTuple):
     timelines: Optional[list] = None
 
 
-#: Process-wide set of executable keys already compiled, for compile
-#: accounting (jit itself also caches; this mirrors its keying).
-_COMPILED_KEYS: set = set()
+#: Host spans of :meth:`SweepGrid.run`, outermost first: ``sweep.run``
+#: holds ``sweep.prepare`` and, per bucket, the last four in turn.
+RUN_SPANS: Tuple[str, ...] = ("sweep.run", "sweep.prepare", "sweep.inputs",
+                              "sweep.launch", "sweep.fetch",
+                              "sweep.summarize")
+_RUN, _PREPARE, _INPUTS, _LAUNCH, _FETCH, _SUMMARIZE = RUN_SPANS
+
+#: Process-wide {executable key: (jitted callable, abstract arguments)}
+#: of the executables dispatched so far, for compile accounting (jit
+#: itself also caches; this mirrors its keying) and :func:`compiled_hlo`.
+_COMPILED_KEYS: Dict[tuple, tuple] = {}
 
 #: Memoized sharded callables per (group, structure, n_devices).
 _EXEC_MEMO: Dict[tuple, object] = {}
@@ -123,6 +141,32 @@ _EXEC_MEMO: Dict[tuple, object] = {}
 def compile_count() -> int:
     """Total sweep executables compiled by this process so far."""
     return len(_COMPILED_KEYS)
+
+
+def compiled_hlo() -> List[Tuple[str, str]]:
+    """(module name, optimised HLO text) of every executable
+    :meth:`SweepGrid.run` has dispatched in this process, in first-use
+    order.
+
+    Each is lowered again from the abstract arguments (shape, dtype,
+    sharding) recorded at its first dispatch and compiled, which is a
+    compile or a persistent-cache read: call it after timed work, never
+    inside it. The text keeps each instruction's ``op_name`` metadata.
+    """
+    out = []
+    for fn, abstract in _COMPILED_KEYS.values():
+        text = fn.lower(abstract).compile().as_text()
+        out.append((text.split(None, 2)[1].rstrip(","), text))
+    return out
+
+
+def _abstract(tree):
+    """Shape, dtype and (where committed) sharding of every array leaf
+    of ``tree``: what ``jit`` lowers the concrete leaves as."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=a.sharding if a.committed else None),
+        tree)
 
 
 def _sharded_executable(group: Tuple[str, ...], nocs: Tuple[str, ...],
@@ -209,6 +253,32 @@ def _signature(group: Tuple[str, ...], arch: str, structure: GeomStructure,
                                          round_shape, insn_shape, n_apps,
                                          noc_group, noc, probe_backend)
     return _SIG_MEMO[key]
+
+
+def _bucket_arrays(pts: Sequence[SweepPoint], kind: tuple, split,
+                   group: Tuple[str, ...], noc_group: Tuple[str, ...]):
+    """One bucket's stacked point arrays, on the device, in the order
+    :func:`repro.core.simulator._sim_core` takes them."""
+    _, insn_shape, _ = kind
+    addr = jnp.asarray(np.stack([p.trace.addr for p in pts]), jnp.int32)
+    is_write = jnp.asarray(
+        np.stack([p.trace.is_write for p in pts]), bool)
+    if insn_shape == ():
+        insn = jnp.asarray([p.trace.insn_per_req for p in pts],
+                           jnp.float32)
+    else:
+        insn = jnp.asarray(
+            np.stack([p.trace.insn_per_req for p in pts]), jnp.float32)
+    core_app = jnp.asarray(
+        np.stack([p.trace.core_app_ids for p in pts]), jnp.int32)
+    scalars = jax.tree.map(
+        lambda *leaves: jnp.stack(leaves),
+        *[split(p.geom)[1] for p in pts])
+    policy_idx = jnp.asarray(
+        [group.index(p.arch) for p in pts], jnp.int32)
+    noc_idx = jnp.asarray(
+        [noc_group.index(p.noc) for p in pts], jnp.int32)
+    return (addr, is_write, insn, core_app, scalars, policy_idx, noc_idx)
 
 
 class SweepGrid:
@@ -334,21 +404,9 @@ class SweepGrid:
                             "they cannot stack into one executable; "
                             f"give {noc!r} its own stack_key")
 
-    def run(self, n_devices: Optional[int] = None, *,
-            telemetry: Optional[TelemetryConfig] = None) -> SweepRun:
-        """Sweep every grid point; one sharded dispatch per bucket.
-
-        ``telemetry`` (static, hashable) threads windowed
-        observability through every bucket: the returned
-        :class:`SweepRun` gains a per-point ``timelines`` list
-        (``repro.obs.SimTimeline``, aligned with :attr:`points`) and
-        per-point results stay bit-equal to the default run. ``None``
-        reuses exactly the pre-telemetry executables.
-
-        ``n_devices`` (default: every local device) must not exceed the
-        devices present; asking for more raises.
-        """
-        t0 = time.perf_counter()
+    def _prepare(self, n_devices: Optional[int],
+                 telemetry: Optional[TelemetryConfig]):
+        """(device count, {bucket key: point indices}, geometry split)."""
         if telemetry is not None:
             for p in self.points:
                 telemetry.window_for(p.trace.addr.shape[0])
@@ -387,73 +445,84 @@ class SweepGrid:
                    split(p.geom)[0], trace_kind(p.trace),
                    p.probe_backend)
             buckets.setdefault(key, []).append(i)
+        return D, buckets, split
 
-        results: List[Optional[SimResult]] = [None] * len(self.points)
-        timelines: Optional[list] = (
-            [None] * len(self.points) if telemetry is not None else None)
-        used_execs: set = set()
-        spanned: set = set()
-        new_compiles = 0
-        for (group, noc_group, structure, kind, backend), idxs \
-                in buckets.items():
-            _, insn_shape, n_apps = kind
-            B = len(idxs)
-            pad = (-B) % D
-            rows = idxs + [idxs[-1]] * pad          # repeat last point
-            pts = [self.points[i] for i in rows]
-            addr = jnp.asarray(np.stack([p.trace.addr for p in pts]),
-                               jnp.int32)
-            is_write = jnp.asarray(
-                np.stack([p.trace.is_write for p in pts]), bool)
-            if insn_shape == ():
-                insn = jnp.asarray([p.trace.insn_per_req for p in pts],
-                                   jnp.float32)
-            else:
-                insn = jnp.asarray(
-                    np.stack([p.trace.insn_per_req for p in pts]),
-                    jnp.float32)
-            core_app = jnp.asarray(
-                np.stack([p.trace.core_app_ids for p in pts]), jnp.int32)
-            scalars = jax.tree.map(
-                lambda *leaves: jnp.stack(leaves),
-                *[split(p.geom)[1] for p in pts])
-            policy_idx = jnp.asarray(
-                [group.index(p.arch) for p in pts], jnp.int32)
-            noc_idx = jnp.asarray(
-                [noc_group.index(p.noc) for p in pts], jnp.int32)
-            exec_key = (group, noc_group, structure, kind, backend,
-                        B + pad, D, telemetry)
-            used_execs.add(exec_key)
-            if exec_key not in _COMPILED_KEYS:
-                _COMPILED_KEYS.add(exec_key)
-                new_compiles += 1
-            fn = _sharded_executable(group, noc_group, structure, D,
-                                     n_apps, backend, telemetry)
-            out = fn((addr, is_write, insn, core_app, scalars, policy_idx,
-                      noc_idx))
-            spanned |= out["cycles"].sharding.device_set
-            stats = jax.device_get(out)
-            snaps = stats.pop("timeline", None)
-            for b, i in enumerate(idxs):
-                p = self.points[i]
-                results[i] = _summarize(
-                    jax.tree.map(lambda a: a[b], stats), p.trace)
-                if telemetry is not None:
-                    from repro.obs.timeline import SimTimeline
-                    timelines[i] = SimTimeline.from_snapshots(
-                        jax.tree.map(lambda a: a[b], snaps), telemetry,
-                        rounds=p.trace.addr.shape[0],
-                        meta={"arch": p.arch, "noc": p.noc,
-                              "n_apps": p.trace.n_apps,
-                              "n_cores": p.trace.n_cores,
-                              "probe_backend": p.probe_backend})
+    def _summarize_bucket(self, idxs, stats, results, timelines,
+                          telemetry) -> None:
+        """Fill ``results`` (and ``timelines``) at ``idxs`` from one
+        bucket's fetched stats."""
+        snaps = stats.pop("timeline", None)
+        for b, i in enumerate(idxs):
+            p = self.points[i]
+            results[i] = _summarize(
+                jax.tree.map(lambda a: a[b], stats), p.trace)
+            if telemetry is not None:
+                from repro.obs.timeline import SimTimeline
+                timelines[i] = SimTimeline.from_snapshots(
+                    jax.tree.map(lambda a: a[b], snaps), telemetry,
+                    rounds=p.trace.addr.shape[0],
+                    meta={"arch": p.arch, "noc": p.noc,
+                          "n_apps": p.trace.n_apps,
+                          "n_cores": p.trace.n_cores,
+                          "probe_backend": p.probe_backend})
 
-        report = SweepReport(
-            n_points=len(self.points),
-            n_executables=len(used_execs),
-            n_compiles=new_compiles,
-            n_devices=len(spanned),
-            wall_s=time.perf_counter() - t0,
-        )
+    def run(self, n_devices: Optional[int] = None, *,
+            telemetry: Optional[TelemetryConfig] = None) -> SweepRun:
+        """Sweep every grid point; one sharded dispatch per bucket.
+
+        ``telemetry`` (static, hashable) threads windowed
+        observability through every bucket: the returned
+        :class:`SweepRun` gains a per-point ``timelines`` list
+        (``repro.obs.SimTimeline``, aligned with :attr:`points`) and
+        per-point results stay bit-equal to the default run. ``None``
+        reuses exactly the pre-telemetry executables.
+
+        ``n_devices`` (default: every local device) must not exceed the
+        devices present; asking for more raises.
+        """
+        t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation(_RUN):
+            with jax.profiler.TraceAnnotation(_PREPARE):
+                D, buckets, split = self._prepare(n_devices, telemetry)
+            results: List[Optional[SimResult]] = [None] * len(self.points)
+            timelines: Optional[list] = (
+                [None] * len(self.points) if telemetry is not None
+                else None)
+            used_execs: set = set()
+            spanned: set = set()
+            new_compiles = 0
+            for (group, noc_group, structure, kind, backend), idxs \
+                    in buckets.items():
+                with jax.profiler.TraceAnnotation(_INPUTS):
+                    B = len(idxs)
+                    pad = (-B) % D
+                    rows = idxs + [idxs[-1]] * pad      # repeat last point
+                    args = _bucket_arrays(
+                        [self.points[i] for i in rows], kind, split,
+                        group, noc_group)
+                with jax.profiler.TraceAnnotation(_LAUNCH):
+                    exec_key = (group, noc_group, structure, kind, backend,
+                                B + pad, D, telemetry)
+                    used_execs.add(exec_key)
+                    fn = _sharded_executable(group, noc_group, structure, D,
+                                             kind[2], backend, telemetry)
+                    if exec_key not in _COMPILED_KEYS:
+                        _COMPILED_KEYS[exec_key] = (fn, _abstract(args))
+                        new_compiles += 1
+                    out = fn(args)
+                with jax.profiler.TraceAnnotation(_FETCH):
+                    spanned |= out["cycles"].sharding.device_set
+                    stats = jax.device_get(out)
+                with jax.profiler.TraceAnnotation(_SUMMARIZE):
+                    self._summarize_bucket(idxs, stats, results, timelines,
+                                           telemetry)
+
+            report = SweepReport(
+                n_points=len(self.points),
+                n_executables=len(used_execs),
+                n_compiles=new_compiles,
+                n_devices=len(spanned),
+                wall_s=time.perf_counter() - t0,
+            )
         return SweepRun(results=results, report=report,  # type: ignore
                         timelines=timelines)

@@ -137,6 +137,7 @@ def _probe_rank_call(set_idx, qtag, core, cbase, live, deny, tags, state,
         out_shape=[jax.ShapeDtypeStruct((R, 1), jnp.int32)] * 5
         + [jax.ShapeDtypeStruct((1, C), jnp.int32)],   # final port counts
         interpret=interpret,
+        name="ata_probe_rank",
     )(set_idx, qtag, core, cbase, live, deny, tags, state)
 
 
