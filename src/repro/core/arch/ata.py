@@ -8,6 +8,7 @@ paper's core contention win.
 from __future__ import annotations
 
 import dataclasses
+from typing import ClassVar
 
 import jax.numpy as jnp
 
@@ -20,6 +21,7 @@ from repro.core.probe import fused_probe_rank
 @dataclasses.dataclass(frozen=True)
 class AtaPolicy(ArchPolicy):
     name: str = "ata"
+    fills_own_core: ClassVar[bool] = True
 
     @property
     def stack_key(self) -> str:
@@ -76,8 +78,8 @@ class AtaPolicy(ArchPolicy):
             local_hits = local_hits | vserved
             l1_time = jnp.where(vserved,
                                 geom.lat_l1 + float(TAG_CHECK), l1_time)
-        l1 = tagarray.touch(l1, reqs.core, set_idx, way, t, local_hit,
-                            set_dirty=reqs.is_write)
+        l1 = tagarray.touch_rows(l1, set_idx, way, t, local_hit,
+                                 set_dirty=reqs.is_write)
         return L1Outcome(
             l1=l1,
             served=served,

@@ -19,7 +19,7 @@ core edits required.
 from __future__ import annotations
 
 import dataclasses
-from typing import NamedTuple, Optional, Union
+from typing import ClassVar, NamedTuple, Optional, Union
 
 import jax.numpy as jnp
 
@@ -34,7 +34,10 @@ TAG_CHECK = 8
 class RequestBatch(NamedTuple):
     """One round's flattened requests plus derived routing indices.
 
-    R = n_cores * m requests; G = cluster size.
+    R = n_cores * m requests, core-major: request ``c * m + j`` is core
+    ``c``'s ``j``-th, so an ``(R,)`` field is ``(n_cores, m)`` rows and
+    updates of each core's own L1 take ``tagarray``'s row form.
+    G = cluster size.
     """
     addr: jnp.ndarray        # (R,) int32 line addresses
     is_write: jnp.ndarray    # (R,) bool
@@ -94,7 +97,14 @@ class ArchPolicy:
     with family members that ignore it: the extension arrays are
     zero-sized when nobody asks for them (existing goldens stay
     bit-exact) and dead weight in the branches that do not read them.
+
+    ``fills_own_core`` declares that the policy's ``fill_cache`` is
+    always ``reqs.core``, the requester's own L1: the shared fill stage
+    then updates the L1 with ``tagarray.fill_rows`` instead of a
+    scatter. A dataflow property of the class, not a knob.
     """
+    fills_own_core: ClassVar[bool] = False
+
     name: str
     replacement: ReplacementPolicy = ReplacementPolicy.LRU
     victim_ways: int = 0

@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import dataclasses
+from typing import ClassVar
 
 import jax.numpy as jnp
 
@@ -13,6 +14,7 @@ from repro.core.geometry import GpuGeometry
 @dataclasses.dataclass(frozen=True)
 class PrivatePolicy(ArchPolicy):
     name: str = "private"
+    fills_own_core: ClassVar[bool] = True
 
     def l1_stage(self, geom: GpuGeometry, l1: tagarray.TagState,
                  reqs: RequestBatch, t, *,
@@ -21,8 +23,8 @@ class PrivatePolicy(ArchPolicy):
         R = reqs.n_requests
         hit, way, _ = tagarray.probe(l1, reqs.core, reqs.set_idx, reqs.addr,
                                      policy=self.replacement)
-        l1 = tagarray.touch(l1, reqs.core, reqs.set_idx, way, t, hit,
-                            set_dirty=reqs.is_write)
+        l1 = tagarray.touch_rows(l1, reqs.set_idx, way, t, hit,
+                                 set_dirty=reqs.is_write)
         return L1Outcome(
             l1=l1,
             served=hit,

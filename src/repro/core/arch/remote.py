@@ -7,6 +7,7 @@ ends up coming from L2.
 from __future__ import annotations
 
 import dataclasses
+from typing import ClassVar
 
 import jax.numpy as jnp
 
@@ -19,6 +20,7 @@ from repro.core.geometry import GpuGeometry
 @dataclasses.dataclass(frozen=True)
 class RemotePolicy(ArchPolicy):
     name: str = "remote"
+    fills_own_core: ClassVar[bool] = True
 
     def l1_stage(self, geom: GpuGeometry, l1: tagarray.TagState,
                  reqs: RequestBatch, t, *,
@@ -50,8 +52,8 @@ class RemotePolicy(ArchPolicy):
             occupancy,
             jnp.where(remote_hit,
                       psize.astype(jnp.float32) * geom.svc_port, 0.0))
-        l1 = tagarray.touch(l1, reqs.core, set_idx, way, t, hit,
-                            set_dirty=reqs.is_write)
+        l1 = tagarray.touch_rows(l1, set_idx, way, t, hit,
+                                 set_dirty=reqs.is_write)
         return L1Outcome(
             l1=l1,
             served=hit | remote_hit,

@@ -408,8 +408,16 @@ def _round(policy: ArchPolicy, nocs: Sequence[NocModel], noc_idx,
             fill_mask = fill_mask & ~out.bypass_fill
         _, fway, _ = tagarray.probe(l1, out.fill_cache, out.fill_set, addr,
                                     policy=policy.replacement)
-        l1, wb = tagarray.fill(l1, out.fill_cache, out.fill_set, fway, addr, t,
-                               fill_mask, dirty=reqs.is_write)
+        if policy.fills_own_core:
+            if out.fill_cache is not reqs.core:
+                raise ValueError(
+                    f"{policy.name!r} declares fills_own_core but its "
+                    "fill_cache is not reqs.core")
+            l1, wb = tagarray.fill_rows(l1, out.fill_set, fway, addr, t,
+                                        fill_mask, dirty=reqs.is_write)
+        else:
+            l1, wb = tagarray.fill(l1, out.fill_cache, out.fill_set, fway,
+                                   addr, t, fill_mask, dirty=reqs.is_write)
         noc_flits = noc_flits + jnp.sum(wb) * geom.flits_per_line
 
     # ---- NoC stage: remote-probe/remote-data flits through the active

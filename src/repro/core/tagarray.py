@@ -43,6 +43,19 @@ would revert it — e.g. a core-0 fill undone, a dirty bit lost, a missed
 write-back.) Within the masked-*in* requests, duplicate
 (array, set, way) targets still resolve last-writer-wins, matching a
 single-ported fill path.
+
+Row form: where every request writes into its *own* array — request
+``j`` of row ``a`` into array ``a``, as the simulator's round orders
+each core's ``m`` requests — ``touch_rows``/``fill_rows`` apply the
+same update with no scatter. Each array field is rewritten by ``k``
+in-order masked selects of a one-hot over ``(set, way)``, one per
+slot, so duplicate targets within a row resolve last-writer-wins in
+slot order by construction, under ``vmap`` too. They equal
+``touch``/``fill`` fed ``array_idx = repeat(arange(n_arrays), k)`` on
+every field (a hypothesis test asserts this). A TPU applies a
+scatter's updates one after another (about 80 ns each on a v5e at
+paper geometry); the selects fuse into elementwise passes over the
+state.
 """
 from __future__ import annotations
 
@@ -194,6 +207,78 @@ def fill(state: TagState, array_idx, set_idx, way, addr, now,
     # counters) ride through untouched.
     return dict(state, tags=tags, last=last, born=born, valid=valid,
                 dirty=dirty_arr), evicted_dirty
+
+
+def _rows(state: TagState, x) -> jnp.ndarray:
+    """A request field as ``(n_arrays, k)`` rows (array-major order)."""
+    return jnp.reshape(jnp.asarray(x), (state["tags"].shape[0], -1))
+
+
+def _slot_targets(state: TagState, set_idx, way, mask):
+    """Per slot ``j``, the ``(n_arrays, n_sets, n_ways)`` one-hot of each
+    row's masked-in ``(set_idx[:, j], way[:, j])`` target.
+
+    Entries are matched by their flat index ``set * n_ways + way`` (one
+    compare per slot, which XLA fuses into the selects), so targets must
+    be in range, as ``probe``'s sets and ways always are."""
+    _, n_sets, n_ways = state["tags"].shape
+    entry = jnp.arange(n_sets * n_ways, dtype=jnp.int32).reshape(
+        1, n_sets, n_ways)
+    target = jnp.where(mask, set_idx * n_ways + way, -1)
+    return [entry == target[:, j, None, None]
+            for j in range(set_idx.shape[1])]
+
+
+def touch_rows(state: TagState, set_idx, way, now, mask, *,
+               set_dirty=None) -> TagState:
+    """``touch`` where slot ``j`` of row ``a`` targets array ``a``.
+
+    Fields are ``(n_arrays, k)``, or their flat array-major
+    ``(n_arrays * k,)`` form. See the row form in the module docstring.
+    """
+    hits = _slot_targets(state, _rows(state, set_idx), _rows(state, way),
+                         _rows(state, mask))
+    last = state["last"]
+    for hit in hits:
+        last = jnp.where(hit, jnp.maximum(last, now), last)
+    out = dict(state, last=last)
+    if set_dirty is not None:
+        set_dirty = _rows(state, set_dirty)
+        dirty = state["dirty"]
+        for j, hit in enumerate(hits):
+            dirty = dirty | (hit & set_dirty[:, j, None, None])
+        out["dirty"] = dirty
+    return out
+
+
+def fill_rows(state: TagState, set_idx, way, addr, now, mask, *,
+              dirty=None) -> Tuple[TagState, jnp.ndarray]:
+    """``fill`` where slot ``j`` of row ``a`` targets array ``a``.
+
+    Fields are ``(n_arrays, k)``, or their flat array-major
+    ``(n_arrays * k,)`` form; ``evicted_dirty`` comes back in ``mask``'s
+    shape, read from the state before any slot writes. See the row form
+    in the module docstring.
+    """
+    shape = jnp.shape(mask)
+    set_idx, way, addr, mask = (_rows(state, x)
+                                for x in (set_idx, way, addr, mask))
+    new_dirty = (_rows(state, dirty) if dirty is not None
+                 else jnp.zeros(addr.shape, bool))
+    rows = jnp.arange(addr.shape[0], dtype=jnp.int32)[:, None]
+    evicted_dirty = (mask & state["valid"][rows, set_idx, way]
+                     & state["dirty"][rows, set_idx, way])
+    hits = _slot_targets(state, set_idx, way, mask)
+    tags, valid, last, born, dirty_arr = (
+        state[k] for k in ("tags", "valid", "last", "born", "dirty"))
+    for j, hit in enumerate(hits):
+        tags = jnp.where(hit, addr[:, j, None, None], tags)
+        valid = valid | hit
+        last = jnp.where(hit, jnp.maximum(last, now), last)
+        born = jnp.where(hit, now, born)
+        dirty_arr = jnp.where(hit, new_dirty[:, j, None, None], dirty_arr)
+    return dict(state, tags=tags, last=last, born=born, valid=valid,
+                dirty=dirty_arr), evicted_dirty.reshape(shape)
 
 
 def dead_victim(state: TagState, array_idx: jnp.ndarray,

@@ -184,6 +184,68 @@ def test_fill_and_touch_scatter_mask_invariants(data):
 
 
 # ---------------------------------------------------------------------------
+# row form: touch_rows/fill_rows equal touch/fill on each row's own array
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("k", [1, 2, 4])
+@settings(max_examples=25, deadline=None)
+@given(data=st.data())
+def test_row_form_equals_scatter_form(k, data):
+    n_arrays, n_sets, n_ways = 3, 2, 2
+    shape = (n_arrays, n_sets, n_ways)
+
+    def draw(elems, dims):
+        n = int(np.prod(dims))
+        return np.asarray(data.draw(
+            st.lists(elems, min_size=n, max_size=n))).reshape(dims)
+
+    state = tagarray.init_tag_state(*shape)
+    state.update(
+        tags=jnp.asarray(draw(st.integers(0, 9), shape), jnp.int32),
+        last=jnp.asarray(draw(st.integers(-1, 9), shape), jnp.int32),
+        born=jnp.asarray(draw(st.integers(-1, 9), shape), jnp.int32),
+        valid=jnp.asarray(draw(st.booleans(), shape)),
+        dirty=jnp.asarray(draw(st.booleans(), shape)))
+    rows = (n_arrays, k)
+    s = draw(st.integers(0, n_sets - 1), rows)
+    w = draw(st.integers(0, n_ways - 1), rows)
+    addr = draw(st.integers(10, 99), rows)
+    mask = draw(st.booleans(), rows)
+    flags = draw(st.booleans(), rows)            # set_dirty / fill dirty
+    now = data.draw(st.integers(0, 9))
+    # a duplicate (set, way) target within row 0, both writers masked
+    # in, and a masked-out lane in row 1
+    s[0, -1], w[0, -1] = s[0, 0], w[0, 0]
+    mask[0, 0] = mask[0, -1] = True
+    mask[1, 0] = False
+
+    a = jnp.asarray(np.arange(n_arrays).repeat(k), jnp.int32)
+    fs, fw, faddr, fmask, fflags = (jnp.asarray(x.ravel())
+                                    for x in (s, w, addr, mask, flags))
+    rs, rw, raddr, rmask, rflags = (jnp.asarray(x)
+                                    for x in (s, w, addr, mask, flags))
+    if not data.draw(st.booleans()):             # the flags left out
+        fflags = rflags = None
+    t = jnp.int32(now)
+
+    want = tagarray.touch(state, a, fs, fw, t, fmask, set_dirty=fflags)
+    got = tagarray.touch_rows(state, rs, rw, t, rmask, set_dirty=rflags)
+    want_f, want_ev = tagarray.fill(state, a, fs, fw, faddr, t, fmask,
+                                    dirty=fflags)
+    got_f, got_ev = tagarray.fill_rows(state, rs, rw, raddr, t, rmask,
+                                       dirty=rflags)
+    for name, w_state, g_state in (("touch", want, got),
+                                   ("fill", want_f, got_f)):
+        assert set(w_state) == set(g_state)
+        for key in w_state:
+            np.testing.assert_array_equal(np.asarray(g_state[key]),
+                                          np.asarray(w_state[key]),
+                                          err_msg=f"{name}: {key}")
+    assert got_ev.shape == rows
+    np.testing.assert_array_equal(np.asarray(got_ev).ravel(),
+                                  np.asarray(want_ev))
+
+
+# ---------------------------------------------------------------------------
 # policy-zoo degeneracy: zero-sized extensions change nothing, bit-exactly
 # ---------------------------------------------------------------------------
 #: Small geometry so random traces exercise hits, misses and evictions.
