@@ -5,7 +5,10 @@ architectures, on the configuration's geometry, with every other
 ``SweepGrid`` default left alone (``lax`` probe, ``ideal`` NoC, all
 local devices). A traffic file with ``"grid": "per_point"`` runs the
 same points as one single-point grid each, back to back: no bucket
-stacks two points under ``vmap``. ``correct`` compares every field of every point of
+stacks two points under ``vmap``. A traced run's unit holds the first
+``"traced_runs"`` grid runs (all where the file names none): the
+profiler keeps a fixed number of events, and the traced window has to
+hold every event of the runs it divides by. ``correct`` compares every field of every point of
 every grid run in the window with the plain reference
 (``bench.reference.sim_ref``).
 """
@@ -17,6 +20,10 @@ import numpy as np
 
 from bench.reference import sim_ref
 from bench.traffic import sim_traces
+
+#: The simulator readers (``bench/metrics/sim.*``) read every entry of
+#: this family: one that runs ``SweepGrid``s, with its spans.
+FAMILY = "sim"
 
 #: Counters: integers, compared exactly (gap in counts).
 COUNTERS = ("local", "remote", "requests", "lat_n", "lat_sum", "l2", "dram",
@@ -97,10 +104,18 @@ def reference_points(config: dict, points, dtype=np.float64):
     return out
 
 
+def compiled_hlo():
+    """(module name, optimised HLO text) of every executable the sweep
+    dispatched, which ``bench/stages.py`` matches the trace against."""
+    from repro.core import sweep
+    return sweep.compiled_hlo()
+
+
 class Cell:
     """One simulator cell: traffic from the seed, its grid, its check."""
 
-    def __init__(self, config: dict, traffic: dict, seed: int):
+    def __init__(self, config: dict, traffic: dict, seed: int,
+                 traced: bool = False):
         from repro.core import GpuGeometry, SweepGrid
         from repro.core.simulator import Trace
         self.config = config
@@ -115,7 +130,16 @@ class Cell:
         else:
             self.grids = [SweepGrid(traffic["archs"], [geom],
                                     [Trace(*tr) for tr in traces])]
-        self.requests_per_unit = sum(tr[0].size for _, tr in self.points)
+        if traced:
+            # the first ``traced_runs`` grid runs, which the profiler
+            # keeps whole; their points are the first points
+            self.grids = self.grids[:traffic.get("traced_runs")]
+            self.points = self.points[:sum(len(g.points)
+                                           for g in self.grids)]
+        #: requests of each ``SweepGrid.run`` of a unit, in order
+        self.run_requests = [sum(p.trace.addr.size for p in g.points)
+                             for g in self.grids]
+        self.requests_per_unit = sum(self.run_requests)
         self._want = None
 
     def reference(self):

@@ -9,15 +9,26 @@ Everything that belongs to one cell is found by name:
   comparison that decides ``correct``;
 * ``bench/traffic/<traffic>.json`` holds the traffic mix's parameters;
 * ``bench/entries/<entry>.py`` builds the cell (traffic from the seed,
-  the work unit, the check against the plain reference);
-* ``bench/metrics/<metric>.py`` reads one metric from the run.
+  the work unit, the check against the plain reference; a traced run's
+  cell, ``Cell(..., traced=True)``, runs the fixed set of work its
+  traffic file sizes to fit the profiler) and names the entry's
+  ``FAMILY``, which the metric readers key on;
+* ``bench/metrics/<metric>.py`` reads one metric from the run, or
+  ``bench/metrics/<metric>.json`` names a ``scope`` (device time of the
+  operations under that ``jax.named_scope``, ns per request) or a
+  ``span`` (device idle time under that program span, % of the traced
+  window), which :func:`read_split_metric` reads from
+  ``bench/stages.py``'s split of a run whose entry gives its program's
+  HLO.
 
 A run: set-up (persistent compile cache, traffic from the seed, one
 warm-up unit that compiles every program the window runs), then a
 closed loop of work units, back to back, until ``--seconds`` have
 passed (the window closes when the last unit started before the
 deadline completes), then the comparison with the reference, then one
-JSON line on stdout.
+JSON line on stdout. A traced run's window is one unit of its traced
+cell: every operation is an event, and the profiler keeps a fixed
+number of them.
 """
 from __future__ import annotations
 
@@ -35,9 +46,6 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "bench")
 TRACE_DIR = os.path.join(ROOT, ".bench_trace")
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-#: A traced run's window: every operation of every loop iteration is an
-#: event, so a few seconds of units already make a trace of millions.
-TRACE_SECONDS = 2.0
 
 
 class NoChip(RuntimeError):
@@ -79,10 +87,28 @@ def entry_module(config: dict):
     return importlib.import_module("bench.entries." + config["entry"])
 
 
-def read_metric(metric: dict, ctx: dict):
-    mod = load_module(os.path.join(BENCH, "metrics", metric["name"] + ".py"),
+def read_metric(metric: dict, ctx: dict, err=None):
+    path = os.path.join(BENCH, "metrics", metric["name"])
+    if os.path.isfile(path + ".json"):
+        return read_split_metric(load_json(path + ".json"), ctx, err)
+    mod = load_module(path + ".py",
                       "bench_metric_" + metric["name"].replace(".", "_"))
     return mod.read(ctx)
+
+
+def read_split_metric(desc: dict, ctx: dict, err=None):
+    """A metric its data file describes: the ``scope``'s device time in
+    ns per request of the traced window's grid runs, or the % of the
+    traced window the devices idle under the ``span``; None without a
+    split, or where a request failed."""
+    from bench import stages
+    r = ctx.get("split")
+    if r is None:
+        return None
+    if "scope" in desc:
+        return (None if ctx["failed"]
+                else stages.scope_ns_per_req(r, desc["scope"], err))
+    return stages.idle_share(r, desc["span"], err)
 
 
 class CompileClock:
@@ -129,8 +155,9 @@ def device_block(devices) -> dict:
 
 def run_cell(spec: dict, name: str, *, seed: int, seconds: float,
              trace: bool, t0: float, require_chip: bool = True,
-             files=None, out=sys.stdout, err=sys.stderr) -> dict:
-    """One run of cell ``name``; prints and returns the result object.
+             files=None, out=sys.stdout, err=sys.stderr):
+    """One run of cell ``name``; prints the result object and returns
+    it with what its metrics were read from.
 
     ``require_chip=False`` lets a test drive the rest of a run on
     whatever devices JAX has, and ``files`` hands it the cell's
@@ -150,12 +177,12 @@ def run_cell(spec: dict, name: str, *, seed: int, seconds: float,
 
     with CompileClock() as clock:
         with jax.profiler.TraceAnnotation("bench.setup.traffic"):
-            cell = entry.Cell(config, traffic, seed)
+            cell = entry.Cell(config, traffic, seed, traced=trace)
         with jax.profiler.TraceAnnotation("bench.setup.warm"):
             error = _attempt(cell.unit, err)
         setup_compile_s, setup_compiles = clock.seconds, clock.count
         if trace:
-            seconds = min(seconds, TRACE_SECONDS)
+            seconds = 0.0                           # one unit
             shutil.rmtree(TRACE_DIR, ignore_errors=True)
             jax.profiler.start_trace(TRACE_DIR,
                                      profiler_options=_profile_options())
@@ -184,23 +211,21 @@ def run_cell(spec: dict, name: str, *, seed: int, seconds: float,
           f"{setup_s!r} s ({setup_compiles} compiles, {setup_compile_s!r} s);"
           f" compiles in the window: {window_compiles}", file=err)
 
-    reduced = None
-    if trace:
-        from bench import tracing
-        reduced = tracing.reduce(
-            tracing.load(tracing.find_xplane(TRACE_DIR)),
-            list(range(wl["chips"])))
-        if reduced:
-            device["busy_s"] = sum(reduced["busy_s"]) / len(reduced["busy_s"])
-            device["window_s"] = reduced["window_s"]
-    ctx = dict(entry=config["entry"], setup_s=setup_s, window_s=window_s,
-               units=len(outputs), requests=requests,
+    ctx = dict(entry=config["entry"], family=getattr(entry, "FAMILY", None),
+               setup_s=setup_s, window_s=window_s, units=len(outputs),
+               requests=requests, failed=attempted - requests,
                setup_compile_s=setup_compile_s, setup_compiles=setup_compiles,
-               window_compiles=window_compiles,
-               trace=reduced)
+               window_compiles=window_compiles, trace=None, split=None)
+    if trace:
+        ctx.update(_reduce_trace(entry, cell, list(range(wl["chips"])),
+                                 len(outputs), err))
+        if ctx["trace"]:
+            busy = ctx["trace"]["busy_s"]
+            device["busy_s"] = sum(busy) / len(busy)
+            device["window_s"] = ctx["trace"]["window_s"]
     metrics = {}
     for m in cell_metrics(spec, name, trace):
-        value = read_metric(m, ctx)
+        value = read_metric(m, ctx, err)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
 
@@ -209,16 +234,38 @@ def run_cell(spec: dict, name: str, *, seed: int, seconds: float,
     correct = failed == 0 and all(v <= lim for v, lim in checks.values())
     result = {"correct": correct, "attempted": attempted, "failed": failed,
               "metrics": metrics, "device": device}
-    if reduced:
-        result["breakdown"] = {"device_ops": reduced["device_ops"],
-                               "idle_gaps": reduced["idle_gaps"]}
+    if ctx["trace"]:
+        result["breakdown"] = {"device_ops": ctx["trace"]["device_ops"],
+                               "idle_gaps": ctx["trace"]["idle_gaps"]}
     result["checks"] = {k: {"value": _num(v), "limit": lim}
                         for k, (v, lim) in checks.items()}
     for k, (v, lim) in checks.items():
         print(f"check {k}: {_num(v)!r} limit {lim!r}", file=err)
     err.flush()
     print(json.dumps(result), file=out, flush=True)
-    return result
+    return result, ctx
+
+
+def _reduce_trace(entry, cell, devices, units, err) -> dict:
+    """The traced window, parsed once: {"trace": ``tracing.reduce``'s,
+    "split": ``stages.split``'s where the entry gives its program's
+    HLO, "reduce_s": seconds of both, the HLO's read left out}."""
+    from bench import stages, tracing
+    t = time.perf_counter()
+    profile = tracing.load(tracing.find_xplane(TRACE_DIR))
+    parse_s = time.perf_counter() - t
+    reduced = tracing.reduce(profile, devices)
+    split, hlo_s = None, 0.0
+    if reduced and hasattr(entry, "compiled_hlo"):
+        h = time.perf_counter()
+        hlo = entry.compiled_hlo()
+        hlo_s = time.perf_counter() - h
+        split = stages.split(profile, hlo, devices, cell.run_requests,
+                             units, err=err)
+    reduce_s = time.perf_counter() - t - hlo_s
+    print(f"trace: parsed in {parse_s!r} s, parsed and reduced in "
+          f"{reduce_s!r} s; HLO read in {hlo_s!r} s", file=err)
+    return {"trace": reduced, "split": split, "reduce_s": reduce_s}
 
 
 def _attempt(fn, err):

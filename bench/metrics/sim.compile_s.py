@@ -3,6 +3,6 @@ JAX reports during set-up (``/jax/core/compile/backend_compile_duration``)."""
 
 
 def read(ctx):
-    if ctx["entry"] == "sweep":
+    if ctx["family"] == "sim":
         return ctx["setup_compile_s"]
     return None

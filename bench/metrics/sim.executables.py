@@ -5,7 +5,7 @@ run dispatches to, warmed in set-up."""
 
 
 def read(ctx):
-    if ctx["entry"] == "sweep":
+    if ctx["family"] == "sim":
         from repro.core import sweep
         return sweep.compile_count()
     return None

@@ -4,6 +4,6 @@ of every grid run completed in the window, over the window's seconds
 
 
 def read(ctx):
-    if ctx["entry"] == "sweep" and ctx["window_s"] > 0:
+    if ctx["family"] == "sim" and ctx["window_s"] > 0:
         return ctx["requests"] / ctx["window_s"]
     return None

@@ -1,21 +1,30 @@
-"""Reduce a profiler trace (``.xplane.pb``) to the benchmark's numbers.
+"""Read a profiler trace (``.xplane.pb``) once, and reduce it to the
+benchmark's numbers.
 
 Device planes are the profiler's ``/device:TPU:<n>`` planes; their
-``XLA Ops`` line holds one event per operation the device ran. A
-device is busy where any of its operations runs (the union of their
-intervals) and idle elsewhere in the traced window. The window and the
-host spans come from the benchmark's own ``jax.profiler.TraceAnnotation``
-spans on the host plane (names starting ``bench.``); an idle gap is
-named by the innermost such span that holds its midpoint.
+``XLA Ops`` line holds one event per operation the device ran, and
+their ``XLA Modules`` line one per program execution. A device is busy
+where any of its operations runs (the union of their intervals) and
+idle elsewhere in the traced window. The window and the host spans come
+from ``jax.profiler.TraceAnnotation`` spans on the host plane: the
+benchmark's own (names starting ``bench.``) and the program's
+(``sweep.``). An idle gap of :func:`reduce` is named by the innermost
+``bench.*`` span that holds its midpoint; ``bench/stages.py`` splits by
+the program's spans.
 
 The device clock runs apart from the host's (about 1.5 ms behind it
 on a v5e). Where every program the host issued shows on a device as
-one ``XLA Modules`` event, the device's events are moved by the median
-of (module start - host issue end), pairing both in order; elsewhere
-they stay as recorded.
+one ``XLA Modules`` event, the device's operations and modules are
+moved by the median of (module start - host issue end), pairing both in
+order. The profiler now and then loses a module event and keeps its
+operations: where the module events fall short of the issues, each run
+of operations outside every module event, between two, stands for the
+lost event, starting at its first operation. Where the starts still do
+not match the issues one for one, the clocks stay as recorded.
 """
 from __future__ import annotations
 
+import bisect
 import collections
 import glob
 import os
@@ -25,6 +34,8 @@ OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 ISSUE_EVENT = "tpu::System::Execute=>IssueSequencedEvent"
 WINDOW_SPAN = "bench.window"
+#: Host spans kept: the benchmark's, then the program's.
+BENCH_SPANS, SPAN_PREFIXES = "bench.", ("bench.", "sweep.")
 _DEVICE = re.compile(r"^/device:TPU:(\d+)$")
 
 
@@ -50,30 +61,64 @@ def _merge(intervals):
 
 
 def load(path: str) -> dict:
-    """{"ops": {device: [(start, end, name)]}, "spans": [(start, end,
-    name)]} in nanoseconds on the host's clock (see the module text)."""
+    """The one parse of a trace file: {"ops" and "modules": {device:
+    [(start, end, name)]}, "spans": [(start, end, name)], "issued":
+    [host issue end], "paired" and "skew_ns": {device: ...}}, in
+    nanoseconds, on the host's clock where the device's programs pair
+    with the host's issues (see the module text)."""
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
     ops, modules, spans, issued = {}, {}, [], []
     for plane in data.planes:
         m = _DEVICE.match(plane.name)
         for line in plane.lines:
-            if m and line.name == OPS_LINE:
-                ops.setdefault(int(m.group(1)), []).extend(
+            if m and line.name in (OPS_LINE, MODULES_LINE):
+                into = ops if line.name == OPS_LINE else modules
+                into.setdefault(int(m.group(1)), []).extend(
                     (e.start_ns, e.end_ns, e.name) for e in line.events)
-            elif m and line.name == MODULES_LINE:
-                modules.setdefault(int(m.group(1)), []).extend(
-                    e.start_ns for e in line.events)
             elif not m and plane.name.startswith("/host"):
                 for e in line.events:
-                    if e.name.startswith("bench."):
+                    if e.name.startswith(SPAN_PREFIXES):
                         spans.append((e.start_ns, e.end_ns, e.name))
                     elif e.name == ISSUE_EVENT:
                         issued.append(e.end_ns)
-    for d, events in ops.items():
-        skew = _skew(sorted(modules.get(d, [])), sorted(issued))
-        ops[d] = [(s - skew, e - skew, n) for s, e, n in events]
-    return {"ops": ops, "spans": spans}
+    issued.sort()
+    paired, skews = {}, {}
+    for d in ops.keys() | modules.keys():
+        mods = sorted(modules.get(d, []))
+        paired[d], skew = _align(ops.get(d, []), mods, issued)
+        skews[d] = skew
+        modules[d] = [(s - skew, e - skew, n) for s, e, n in mods]
+        ops[d] = [(s - skew, e - skew, n) for s, e, n in ops.get(d, [])]
+    return {"ops": ops, "modules": modules, "spans": spans,
+            "issued": issued, "paired": paired, "skew_ns": skews}
+
+
+def by_module(events, mods):
+    """Operations grouped by the module event (of ``mods``, sorted)
+    holding their start: {(module index, True): ops}, and {(index of the
+    module event before them, or -1, False): ops} for those outside
+    every module event."""
+    starts = [s for s, _, _ in mods]
+    groups = collections.defaultdict(list)
+    for ev in events:
+        i = bisect.bisect_right(starts, ev[0]) - 1
+        groups[i, i >= 0 and ev[0] < mods[i][1]].append(ev)
+    return groups
+
+
+def _align(ops, mods, issued):
+    """(paired, device clock minus host clock) of one device: its program
+    starts against the host's issue ends, in order. The starts are the
+    module events', and where those fall short of the issues, also the
+    first operation of each run of operations outside every module
+    event (a module event the profiler lost)."""
+    starts = [s for s, _, _ in mods]
+    if len(starts) != len(issued):
+        starts = sorted(starts + [min(g)[0] for (_, inside), g in
+                                  by_module(ops, mods).items() if not inside])
+    paired = bool(starts) and len(starts) == len(issued)
+    return paired, _skew(starts, issued)
 
 
 def _skew(module_starts, issue_ends) -> float:
@@ -95,7 +140,8 @@ def reduce(trace: dict, devices, top: int = 10) -> dict:
         return None
     lo, hi = windows[0]
     spans = sorted((s, e, n) for s, e, n in trace["spans"]
-                   if n != WINDOW_SPAN and e > lo and s < hi)
+                   if n.startswith(BENCH_SPANS) and n != WINDOW_SPAN
+                   and e > lo and s < hi)
     busy, op_time, gap_time = [], collections.Counter(), collections.Counter()
     n = len(devices)
     for d in devices:

@@ -20,7 +20,7 @@ def _run(cell, files):
     out, err = io.StringIO(), io.StringIO()
     return harness.run_cell(SPEC, cell, seed=13, seconds=0.2, trace=False,
                             t0=time.perf_counter(), require_chip=False,
-                            files=files, out=out, err=err)
+                            files=files, out=out, err=err)[0]
 
 
 @pytest.mark.parametrize("cell", CELLS)

@@ -1,16 +1,15 @@
-"""The stage and idle split (``bench/stages.py``): device operations
-matched to the round's stage scopes through the program's HLO, idle
-time cut at the sweep's host spans."""
-import gzip
+"""The scope and idle split (``bench/stages.py``): device operations
+matched to the round's stage scopes through the program's HLO, counted
+over the traced window's grid runs, idle time cut at the sweep's host
+spans."""
+import collections
 import json
-import os
+import types
 
 import pytest
 
-from bench import harness, stages
+from bench import harness, stages, tracing
 
-STAGE_TRACE = os.path.join(harness.BENCH, "testdata", "stages.xplane.pb.gz")
-STAGE_HLO = os.path.join(harness.BENCH, "testdata", "stages.hlo.json.gz")
 
 
 def _line(name, text, op_name=None):
@@ -56,35 +55,46 @@ def _ops(shift=0):
 SPANS = [(200, 9000, "sweep.run"), (200, 600, "sweep.prepare"),
          (600, 900, "sweep.inputs"), (900, 1000, "sweep.launch"),
          (1000, 4300, "sweep.fetch"), (4300, 4800, "sweep.summarize")]
-# device clock 100 ns ahead of the host's: modules start 100 ns after
-# their issue ends
-MODULES = {0: [(1100, 4100, "jit_local_batch(77)"),
-               (5100, 5300, "jit_stack(12)")]}
-ISSUED = [1000, 5000]
+# on the host's clock, as tracing.load leaves them where clocks pair
+MODULES = [(1000, 4000, "jit_local_batch(77)"), (5000, 5300, "jit_stack(12)")]
 
 
-def _reduce(hlo, ops=None, issued=ISSUED, err=None):
-    trace = {"ops": {0: ops if ops is not None else _ops()},
-             "spans": [(0, 10000, "bench.window"), (100, 9500, "bench.unit")]}
-    return stages.reduce(trace, MODULES, issued, SPANS, hlo, [0],
-                         err=err)
+def _trace(ops=None, modules=MODULES, paired=True):
+    return {"ops": {0: ops if ops is not None else _ops()},
+            "modules": {0: modules}, "issued": [900, 4900],
+            "spans": [(0, 10000, "bench.window"),
+                      (100, 9500, "bench.unit")] + SPANS,
+            "paired": {0: paired}, "skew_ns": {0: 100 if paired else 0}}
+
+
+def _split(hlo, ops=None, modules=MODULES, paired=True, err=None):
+    return stages.split(_trace(ops, modules, paired), hlo, [0], [1000], 1,
+                        err=err)
+
+
+def _ns(r, scope):
+    """A scope's time in ns (the synthetic unit has 1000 requests)."""
+    v = stages.scope_ns_per_req(r, scope)
+    return None if v is None else round(v * 1000, 6)
 
 
 def test_synthetic_stage_and_idle_sums(capsys):
-    r = _reduce([_hlo("jit_local_batch", INSTRS)])
-    ns = {k: round(v * 1e9, 6) for k, v in r["stages"].items()}
-    assert ns == {"l1": 500, "probe": 500, "l2": 500, "fill": 500,
-                  "noc": 100, "timing": 500, "other": 400 + 200}
-    assert sum(r["stages"].values()) == pytest.approx(r["self_s"])
-    assert r["self_s"] == pytest.approx(sum(r["busy_s"]))
+    r = _split([_hlo("jit_local_batch", INSTRS)])
+    ns = {s: _ns(r, s) for s in ("l1", "probe", "l2", "fill", "noc",
+                                 "timing", "while")}
+    assert ns == {"l1": 1000, "probe": 500, "l2": 500, "fill": 500,
+                  "noc": 100, "timing": 500, "while": 2600}
+    assert r["requests"] == 1000
+    assert sum(r["scopes"].values()) == pytest.approx(3000e-9)
+    assert r["self_s"] == pytest.approx(3200e-9)
     assert r["mapped_s"] == pytest.approx(3000e-9)
     idle = {k: round(v * 1e9, 6) for k, v in r["idle"].items()}
     assert idle == {"bench": 200 + 1000, "sweep.prepare": 400,
                     "sweep.inputs": 300, "sweep.launch": 100,
                     "sweep.fetch": 300, "sweep.summarize": 500,
                     "sweep.run": 200 + 3800}
-    assert sum(r["idle"].values()) + r["busy_s"][0] == pytest.approx(
-        r["window_s"])
+    assert sum(r["idle"].values()) + 3200e-9 == pytest.approx(r["window_s"])
+    assert stages.idle_share(r, "sweep.inputs") == pytest.approx(3.0)
     assert r["skew_ns"] == [100] and r["paired"]
     assert "pairing held" in capsys.readouterr().err
 
@@ -97,28 +107,32 @@ def test_instruction_text_breaks_a_tie():
               f"{LOOP}/timing/add" if n == "fusion.1" else o)
              for n, t, o in INSTRS]
     hlo = [_hlo("jit_local_batch", other), _hlo("jit_local_batch", INSTRS)]
-    assert _reduce(hlo)["stages"]["l1"] == pytest.approx(500e-9)
+    assert _ns(_split(hlo), "l1") == 1000
     ops = [(s, e, _event("fusion.1", f16) if "fusion.1 " in n else n)
            for s, e, n in _ops()]
-    r = _reduce(hlo, ops)
-    assert r["stages"]["l1"] == 0.0
-    assert r["stages"]["timing"] == pytest.approx(1000e-9)
+    r = _split(hlo, ops)
+    assert _ns(r, "l1") == 500 and _ns(r, "probe") == 500
+    assert _ns(r, "timing") == 1000
 
 
 def test_same_text_two_stages_is_no_split(capsys):
+    """Two executables hold one text under different scopes: those
+    scopes read nothing, the others still read."""
     other = [(n, t, f"{LOOP}/timing/add" if n == "fusion.1" else o)
              for n, t, o in INSTRS]
     hlo = [_hlo("jit_local_batch", other), _hlo("jit_local_batch", INSTRS)]
-    r = _reduce(hlo)
-    assert r["stages"] is None
+    r = _split(hlo)
+    assert stages.scope_ns_per_req(r, "l1") is None
+    assert stages.scope_ns_per_req(r, "timing") is None
+    assert "are and are not under 'l1'" in capsys.readouterr().err
+    assert _ns(r, "l2") == 500 and _ns(r, "probe") == 500
     assert r["idle"] is not None
-    assert "unclear" in capsys.readouterr().err
 
 
 def test_unmatched_instruction_is_no_split(capsys):
     ops = _ops() + [(3600, 3700, "%fusion.99 = f32[8] fusion(%p)")]
-    r = _reduce([_hlo("jit_local_batch", INSTRS)], ops)
-    assert r["stages"] is None
+    r = _split([_hlo("jit_local_batch", INSTRS)], ops)
+    assert r["scopes"] is None and stages.scope_ns_per_req(r, "l2") is None
     assert "fusion.99" in capsys.readouterr().err
 
 
@@ -129,51 +143,117 @@ def test_name_matches_but_text_differs_is_no_split(capsys):
     ops = [(s, e, _event("fusion.3", "f32[8] fusion(%q), kind=kInput, "
                          "calls=%fc") if "fusion.3 " in n else n)
            for s, e, n in _ops()]
-    r = _reduce([_hlo("jit_local_batch", INSTRS)], ops)
-    assert r["stages"] is None and r["idle"] is not None
+    r = _split([_hlo("jit_local_batch", INSTRS)], ops)
+    assert r["scopes"] is None and r["idle"] is not None
     assert "text of fusion.3 differs" in capsys.readouterr().err
 
 
 def test_no_dispatched_module_ran_is_no_split(capsys):
-    r = _reduce([_hlo("jit_other", INSTRS)])
-    assert r["stages"] is None
-    assert "no module the sweep dispatched" in capsys.readouterr().err
+    r = _split([_hlo("jit_other", INSTRS)])
+    assert r["scopes"] is None and r["requests"] is None
+    assert "0 executions of dispatched programs for 1 launches" in \
+        capsys.readouterr().err
 
 
-def test_operation_outside_every_module_event():
-    """Where the profiler lost a module event, an operation is matched
-    by its signature over every dispatched executable; one that none
-    holds is other."""
+def test_operation_outside_every_module_event(capsys):
+    """Where the profiler lost a module event, the operations outside
+    every module event are matched by their text over every dispatched
+    executable, and make one execution. More executions than launches
+    cannot be told apart, before the last module event or after it."""
     f3 = _event("fusion.3", F8)
-    ops = _ops() + [(4500, 4600, f3),
-                    (4700, 4750, "%copy.9 = f32[8] copy(%x)")]
-    r = _reduce([_hlo("jit_local_batch", INSTRS)], ops)
-    assert r["stages"]["l2"] == pytest.approx(600e-9)
-    assert r["stages"]["other"] == pytest.approx(650e-9)
-    assert r["mapped_s"] == pytest.approx(3100e-9)
-    ops = _ops() + [(4500, 4600, _event("fusion.3", "f32[9] add(%a)"))]
-    assert _reduce([_hlo("jit_local_batch", INSTRS)], ops)["stages"][
-        "other"] == pytest.approx(700e-9)
+    lost = _split([_hlo("jit_local_batch", INSTRS)], modules=MODULES[1:])
+    assert lost["requests"] == 1000 and _ns(lost, "l1") == 1000
+    assert _ns(lost, "l2") == 500 and lost["mapped_s"] == pytest.approx(
+        3000e-9)
+    for extra in ([(4500, 4600, f3)], [(5400, 5500, f3)]):
+        r = _split([_hlo("jit_local_batch", INSTRS)], _ops() + extra)
+        assert r["requests"] is None and r["scopes"] is None
+        assert r["self_s"] == pytest.approx(3300e-9)
+        assert "2 executions of dispatched programs for 1 launches" in \
+            capsys.readouterr().err
+
+
+def test_programs_the_trace_lost_leave_the_scopes_unread(capsys):
+    """A trace holding fewer module events, with those lost, than the
+    programs the host issued was cut by the profiler: no scope is read,
+    though each launch shows its execution."""
+    trace = _trace()
+    trace["issued"] = trace["issued"] + [9000]
+    r = stages.split(trace, [_hlo("jit_local_batch", INSTRS)], [0], [1000],
+                     1)
+    assert r["requests"] is None and r["scopes"] is None
+    assert "2 module events and 0 lost for 3 programs issued" in \
+        capsys.readouterr().err
+
+
+def test_an_execution_that_lost_events_leaves_the_scopes_unread(capsys):
+    """Every execution of one program runs as many operations. Where the
+    trace holds fewer in one, the profiler lost events inside it, whose
+    time would go to the loop holding them: its grid run is left out of
+    the scopes, its time and its requests both, and where that is the
+    only run no scope is read. The same two executions whole read the
+    time of both; in two grid runs, the whole one is read alone."""
+    second = [(s + 5000, e + 5000, n) for s, e, n in _ops()[:-1]]
+
+    def split(ops, run_requests=(1000,)):
+        trace = _trace(ops, MODULES + [(6000, 9000, "jit_local_batch(77)")])
+        trace["issued"] = [900, 4900, 5950]
+        trace["spans"].append((5900, 6000, "sweep.launch"))
+        if len(run_requests) == 2:
+            trace["spans"] = [x if x[2] != "sweep.run" else
+                              (200, 5800, "sweep.run")
+                              for x in trace["spans"]] + [
+                (5800, 9000, "sweep.run")]
+        return stages.split(trace, [_hlo("jit_local_batch", INSTRS)], [0],
+                            list(run_requests), 1)
+    whole = split(_ops() + second)
+    assert whole["requests"] == 1000
+    assert _ns(whole, "l2") == 1000 and _ns(whole, "l1") == 2000
+    lossy = _ops() + second[:3] + second[4:]
+    r = split(lossy)
+    assert r["requests"] is None and stages.scope_ns_per_req(r, "l2") is None
+    err = capsys.readouterr().err
+    assert "runs [0] of 1 left out of the scopes: the profiler lost " \
+        "events" in err and "[('jit_local_batch(77)', 6)]" in err
+    assert "no scope read: every grid run lost events" in err
+    both = split(_ops() + second, (1000, 1000))
+    assert both["requests"] == 2000 and _ns(both, "l2") == 500
+    r = split(lossy, (1000, 3000))
+    assert r["requests"] == 1000
+    assert _ns(r, "l2") == 500 and _ns(r, "l1") == 1000
+    assert r["idle"] is not None
+    assert "runs [1] of 2 left out" in capsys.readouterr().err
 
 
 def test_failed_pairing_gives_no_idle_split(capsys):
-    """One issue event for two modules: the clocks stay apart (skew 0),
-    operations and modules both on the device's clock."""
-    r = _reduce([_hlo("jit_local_batch", INSTRS)], _ops(shift=100),
-                issued=[1000])
+    """Clocks that could not be paired leave the idle split unread; the
+    scopes do not need them."""
+    r = _split([_hlo("jit_local_batch", INSTRS)], paired=False)
     assert r["idle"] is None and not r["paired"] and r["skew_ns"] == [0]
-    assert r["stages"]["l1"] == pytest.approx(500e-9)
+    assert stages.idle_share(r, "sweep.prepare") is None
+    assert _ns(r, "l1") == 1000
     assert "pairing failed" in capsys.readouterr().err
 
 
-def test_without_hlo_there_is_no_stage_split():
-    r = _reduce(None)
-    assert r["stages"] is None and r["idle"] is not None
+def test_without_hlo_there_is_no_stage_split(tmp_path, monkeypatch,
+                                             recorded):
+    """An entry that gives no program HLO gets the busy and idle
+    reduction and no split."""
+    import types
+    monkeypatch.setattr(harness, "TRACE_DIR", recorded.dir(tmp_path))
+    got = harness._reduce_trace(types.SimpleNamespace(), None, [0], 1,
+                                err=None)
+    assert got["split"] is None and got["trace"]["busy_s"][0] > 0
+    got = harness._reduce_trace(
+        types.SimpleNamespace(compiled_hlo=recorded.hlo),
+        types.SimpleNamespace(run_requests=recorded.runs), [0], 1, err=None)
+    assert got["split"]["requests"] == 1920 and got["reduce_s"] > 0
 
 
 def test_signature_and_stage_of():
     """A device event types each operand and leaves out metadata and
-    backend configuration; its signature equals its HLO line's."""
+    backend configuration; its signature equals its HLO line's. A scope
+    counts every operation whose op_name holds it as a component."""
     hlo = ('%pad_bitcast_fusion.35 = s32[120,4]{0,1:T(4,128)S(1)} '
            'fusion(%get-tuple-element.1345), kind=kLoop, '
            'calls=%fused_computation.77.clone.clone, backend_config={"flag_'
@@ -192,79 +272,178 @@ def test_signature_and_stage_of():
         "(s32[]{:T(128)}, f32[2]{0})", "while", ("condition=%c", "body=%b"))
     assert stages.signature("%c = f32[] constant(1)") == (
         "c", "f32[]", "constant", ())
-    assert stages.stage_of(f"{LOOP}/l1/probe/jit(argsort)/iota") == "probe"
-    assert stages.stage_of(f"{LOOP}/l2/jit(argsort)/iota") == "l2"
-    assert stages.stage_of(f"{LOOP}/add") == "other"
-    assert stages.stage_of("") == "other"
+    names = [f"{LOOP}/l1/probe/jit(argsort)/iota", f"{LOOP}/l2/jit(argsort)"
+             "/iota", f"{LOOP}/add", "", f"{LOOP}/l1x/add"]
+    r = {"named": {c for n in names for c in n.split("/")}, "requests": 1000,
+         "scopes": collections.Counter({frozenset([n]): 1e-6 * (i + 1)
+                                        for i, n in enumerate(names)})}
+    assert stages.scope_ns_per_req(r, "probe") == pytest.approx(1.0)
+    assert stages.scope_ns_per_req(r, "l1") == pytest.approx(1.0)
+    assert stages.scope_ns_per_req(r, "l2") == pytest.approx(2.0)
+    assert stages.scope_ns_per_req(r, "while") == pytest.approx(11.0)
 
 
-def _recorded_hlo():
-    with gzip.open(STAGE_HLO, "rt") as f:
-        return [tuple(x) for x in json.load(f)]
+def _recorded_trace(recorded, path):
+    return tracing.load(tracing.find_xplane(recorded.dir(path)))
 
 
-def _traced_run_dir(tmp_path):
-    """A profiler log directory holding the recorded trace, as a traced
-    run leaves it."""
-    dest = tmp_path / "plugins" / "profile" / "t"
-    dest.mkdir(parents=True)
-    with gzip.open(STAGE_TRACE) as f:
-        (dest / "vm.xplane.pb").write_bytes(f.read())
-    return str(tmp_path)
-
-
-def _recorded(tmp_path):
-    from bench import tracing
-    path = tracing.find_xplane(_traced_run_dir(tmp_path))
-    return stages.reduce_file(path, _recorded_hlo(), [0])
-
-
-def test_recorded_chip_trace(tmp_path):
+def test_recorded_chip_trace(tmp_path, recorded):
     """A trace recorded on a TPU v5e by
     ``bench/testdata/record_stage_trace.py``: single-point ``private``
     and ``ata`` grids under the benchmark's spans, with the HLO of both
     executables (the trace is kept gzipped)."""
-    r = _recorded(tmp_path)
-    assert r["paired"]
-    s = r["stages"]
-    for stage in ("l1", "probe", "l2", "fill", "timing"):
-        assert s[stage] > 0, stage
-    assert sum(s.values()) == pytest.approx(r["self_s"], rel=1e-9)
-    assert r["mapped_s"] >= 0.9 * r["self_s"]
-    assert r["self_s"] == pytest.approx(r["busy_s"][0], rel=0.02)
-    assert sum(r["idle"].values()) + r["busy_s"][0] == pytest.approx(
+    trace = _recorded_trace(recorded, tmp_path)
+    r = stages.split(trace, recorded.hlo(), [0], recorded.runs, 1)
+    busy = tracing.reduce(trace, [0])["busy_s"][0]
+    assert r["paired"] and r["requests"] == 1920
+    for scope, s in recorded.scopes_s.items():
+        assert stages.scope_ns_per_req(r, scope) == pytest.approx(
+            1e9 * s / 1920, rel=1e-9), scope
+    for span, s in recorded.idle_s.items():
+        assert stages.idle_share(r, span) == pytest.approx(
+            100 * s / recorded.window_s, rel=1e-9), span
+    assert r["mapped_s"] == pytest.approx(r["self_s"], rel=1e-9)
+    assert r["self_s"] == pytest.approx(busy, rel=0.02)
+    assert sum(r["idle"].values()) + busy == pytest.approx(
         r["window_s"], rel=1e-9)
-    assert r["idle"]["sweep.prepare"] > 0 and r["idle"]["sweep.inputs"] > 0
 
 
-def test_traced_split_line(tmp_path, monkeypatch, capsys):
-    """The split printed after a traced run: the stage times sum to the
-    operations' self time, and the idle shares to at most the window's
+@pytest.mark.parametrize("cut,held", [
+    ("module", 1920), ("first module", 1920),
+    ("module and its operations", None),
+    ("first module and its operations", None),
+    ("module closed at the cut, later programs lost", None),
+    ("module and the end of its operations, later programs lost", None)])
+def test_partial_trace_counts_the_runs_it_holds(tmp_path, cut, held, capsys,
+                                                recorded):
+    """Where the trace lost module events, the scopes divide the time of
+    the window's grid runs by their requests where the trace holds every
+    run, or read nothing: never a part of the runs by the whole unit's
+    requests. A module event lost mid-trace leaves its operations,
+    matched by their text; the profiler's cut loses the programs issued
+    after it, and a run it cut or lost leaves no scope read."""
+    trace = _recorded_trace(recorded, tmp_path)
+    mods = trace["modules"][0]
+    first, second = [m for m in mods if m[2].startswith("jit_local_batch")]
+    mid = (second[0] + second[1]) / 2
+    if cut.startswith("first module"):
+        mods.remove(first)
+        if cut != "first module":
+            trace["ops"][0] = [ev for ev in trace["ops"][0]
+                               if not first[0] <= ev[0] < first[1]]
+    elif cut.startswith("module closed at the cut"):
+        trace["ops"][0] = [ev for ev in trace["ops"][0] if ev[1] < mid]
+        mods[-1] = (second[0], max(e for _, e, _ in trace["ops"][0]),
+                    second[2])
+    else:
+        mods.remove(second)
+        if cut != "module":
+            end = mid if "end" in cut else second[0]
+            trace["ops"][0] = [ev for ev in trace["ops"][0] if ev[0] < end]
+    if cut.endswith("later programs lost"):
+        trace["issued"] = trace["issued"] + [trace["issued"][-1] + 1e5]
+    r = stages.split(trace, recorded.hlo(), [0], recorded.runs, 1)
+    assert r["requests"] == held
+    if held is None:
+        assert stages.scope_ns_per_req(r, "l2") is None
+        assert "no scope read" in capsys.readouterr().err
+        return
+    for scope, v in recorded.scopes_s.items():
+        assert stages.scope_ns_per_req(r, scope) == pytest.approx(
+            1e9 * v / 1920, rel=1e-9), scope
+
+
+def _profile_without(path, lost):
+    """The profile at ``path`` as ``ProfileData.from_file`` gives it, with
+    the module events at the indices ``lost`` of each device dropped and
+    their operations kept, as the profiler now and then leaves them."""
+    import types
+    from jax.profiler import ProfileData
+
+    def ev(e):
+        return types.SimpleNamespace(start_ns=e.start_ns, end_ns=e.end_ns,
+                                     name=e.name)
+    planes = []
+    for plane in ProfileData.from_file(path).planes:
+        lines = []
+        for line in plane.lines:
+            events = sorted((ev(e) for e in line.events),
+                            key=lambda e: e.start_ns)
+            if line.name == tracing.MODULES_LINE:
+                events = [e for i, e in enumerate(events) if i not in lost]
+            lines.append(types.SimpleNamespace(name=line.name,
+                                               events=events))
+        planes.append(types.SimpleNamespace(name=plane.name, lines=lines))
+    return types.SimpleNamespace(planes=planes)
+
+
+@pytest.mark.parametrize("lost,paired", [
+    ((37,), True), ((75,), True), ((37, 75), True), ((10,), False),
+    ((10, 37), False)])
+def test_a_lost_module_event_keeps_the_clocks_paired(
+        tmp_path, monkeypatch, recorded, lost, paired):
+    """The profiler lost a module event of a grid run (the recorded
+    trace's modules 37 and 75) and kept its operations: those operations
+    stand for its start, so the device's clock still moves onto the
+    host's and the idle split reads what the whole trace gives. A
+    staging program's module event (module 10) holds no operation
+    events, so where it is lost nothing stands for it, and the clocks
+    do not pair."""
+    import jax
+    path = tracing.find_xplane(recorded.dir(tmp_path))
+    whole = tracing.load(path)
+    fake = _profile_without(path, set(lost))
+    monkeypatch.setattr(jax.profiler, "ProfileData",
+                        types.SimpleNamespace(from_file=lambda p: fake))
+    trace = tracing.load(path)
+    assert len(trace["modules"][0]) == len(whole["modules"][0]) - len(lost)
+    assert trace["paired"][0] is paired
+    r = stages.split(trace, recorded.hlo(), [0], recorded.runs, 1)
+    if not paired:
+        assert trace["skew_ns"][0] == 0.0 and r["idle"] is None
+        assert stages.idle_share(r, "sweep.prepare") is None
+        assert r["scopes"] is None
+        return
+    assert trace["skew_ns"][0] == pytest.approx(whole["skew_ns"][0],
+                                                abs=1000)
+    assert r["requests"] == 1920
+    for span, s in recorded.idle_s.items():
+        assert stages.idle_share(r, span) == pytest.approx(
+            100 * s / recorded.window_s, rel=1e-3), span
+    for scope, s in recorded.scopes_s.items():
+        assert stages.scope_ns_per_req(r, scope) == pytest.approx(
+            1e9 * s / 1920, rel=1e-9), scope
+
+
+def test_traced_split_line(tmp_path, monkeypatch, capsys, recorded):
+    """The split printed after a traced run, from the run's own
+    reduction: the stage times and ``other`` sum to the operations'
+    self time per request, and the idle shares to at most the window's
     idle share."""
-    from repro.core import sweep
-    trace_dir = _traced_run_dir(tmp_path)
-    r = _recorded(tmp_path / "again")
-    busy = r["busy_s"][0]
-    result = {"correct": True, "attempted": 1000, "failed": 0, "metrics": {
-        "sim.device_ns_per_req": {"value": 1e9 * busy / 1000, "unit": "ns"},
+    trace = _recorded_trace(recorded, tmp_path)
+    busy = tracing.reduce(trace, [0])["busy_s"][0]
+    r = stages.split(trace, recorded.hlo(), [0], recorded.runs, 1)
+    result = {"correct": True, "attempted": 1920, "failed": 0, "metrics": {
+        "sim.device_ns_per_req": {"value": 1e9 * busy / 1920, "unit": "ns"},
         "sim.idle_share": {"value": 100.0 * (1 - busy / r["window_s"]),
                            "unit": "%"}}}
-    monkeypatch.setattr(harness, "TRACE_DIR", trace_dir)
-    monkeypatch.setattr(harness, "run_cell", lambda *a, **k: result)
-    monkeypatch.setattr(sweep, "compiled_hlo", _recorded_hlo)
+
+    def run_cell(*a, **k):
+        return result, {"split": r, "reduce_s": 1.5}
+    monkeypatch.setattr(harness, "run_cell", run_cell)
     wl = {"chips": 1}
     line = stages.traced_split({}, "sim_ata_hi_points", seed=1, seconds=1,
                                t0=0.0, files=(wl, None, None))
     assert json.loads(capsys.readouterr().out.splitlines()[-1]) == line
     assert line["paired"] and line["mapped_share"] >= 90.0
+    assert line["requests"] == 1920 and line["reduce_s"] == 1.5
     s = line["stages_ns_per_req"]
     assert set(s) == {"l1", "probe", "l2", "fill", "noc", "timing", "other"}
-    assert sum(s.values()) == pytest.approx(line["self_ns_per_req"])
-    assert line["self_ns_per_req"] == pytest.approx(
-        line["device_ns_per_req"], rel=0.02)
+    top = sum(v for k, v in s.items() if k != "probe")
+    assert top == pytest.approx(line["device_ns_per_req"], rel=0.02)
     idle = line["idle_share_by_span"]
     assert sum(idle.values()) == pytest.approx(line["idle_share"])
-    assert sum(idle[k] for k in stages.STEPS) <= line["idle_share"]
+    assert sum(idle[k] for k in recorded.idle_s) <= line["idle_share"]
     result["failed"] = 10
     line = stages.traced_split({}, "sim_ata_hi_points", seed=1, seconds=1,
                                t0=0.0, files=(wl, None, None))
@@ -285,7 +464,7 @@ def test_traced_split_runs_a_tiny_cell(tiny, tmp_path, monkeypatch, capsys):
     assert json.loads(result)["correct"] is True
     assert json.loads(split) == line
     assert line["workload"] == cell and line["correct"] is True
-    assert line["stages_ns_per_req"] is None and line["hlo_s"] > 0
+    assert line["stages_ns_per_req"] is None and line["reduce_s"] > 0
 
 
 def test_main_refuses_without_a_tpu(capsys):

@@ -33,6 +33,20 @@ def test_device_clock_is_moved_onto_the_hosts():
     assert tracing._skew([1000], [2500, 6400]) == 0.0
 
 
+def test_a_lost_module_event_pairs_by_its_operations():
+    """Module events fewer than the host's issues: each run of operations
+    outside every module event stands for a lost one, from its first
+    operation. Where nothing stands for it, the clocks do not pair."""
+    mods = [(1000, 1100, "a"), (3000, 3100, "c")]
+    ops = [(1010, 1090, "x"), (2050, 2070, "z"), (2020, 2080, "y"),
+           (3010, 3050, "w")]
+    issued = [500, 1500, 2500]
+    assert tracing._align(ops, mods, issued) == (True, 500)
+    assert tracing._align(ops[:1] + ops[3:], mods, issued) == (False, 0.0)
+    assert tracing._align(ops, sorted(mods + [(2000, 2100, "b")]),
+                          issued) == (True, 500)
+
+
 def test_reduce_averages_devices_and_needs_a_window():
     trace = {"ops": {0: [(0, 50, "a")], 1: [(0, 100, "a")]},
              "spans": [(0, 100, "bench.window")]}

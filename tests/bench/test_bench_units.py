@@ -6,6 +6,7 @@ import json
 import os
 import time
 
+import numpy as np
 import pytest
 
 from bench import harness
@@ -34,9 +35,10 @@ def test_unit_runs_and_checks(traffic_name, tiny):
 @pytest.mark.parametrize("cell", [w["name"] for w in SPEC["workloads"]])
 def test_run_cell_prints_one_result_line(cell, tiny):
     out, err = io.StringIO(), io.StringIO()
-    result = harness.run_cell(SPEC, cell, seed=7, seconds=0.5, trace=False,
-                              t0=time.perf_counter(), require_chip=False,
-                              files=tiny(cell), out=out, err=err)
+    result, _ = harness.run_cell(SPEC, cell, seed=7, seconds=0.5,
+                                 trace=False, t0=time.perf_counter(),
+                                 require_chip=False, files=tiny(cell),
+                                 out=out, err=err)
     line = json.loads(out.getvalue().splitlines()[-1])
     assert line == json.loads(json.dumps(result))
     assert list(line)[:5] == ["correct", "attempted", "failed", "metrics",
@@ -53,3 +55,26 @@ def test_run_cell_prints_one_result_line(cell, tiny):
     tail = err.getvalue().splitlines()[-len(line["checks"]):]
     assert all(t.startswith("check ") and " limit " in t for t in tail)
     assert "compiles in the window: 0" in err.getvalue()
+
+
+@pytest.mark.parametrize("runs", [3, None])
+def test_traced_cell_runs_its_first_grid_runs(runs, tiny):
+    """A traced run's cell holds the first ``traced_runs`` grid runs of
+    the unit (every one where the traffic names none), with their
+    points, and checks just those."""
+    wl, config, traffic = tiny("sim_ata_hi_points")
+    traffic = dict(traffic, traced_runs=runs)
+    entry = harness.entry_module(config)
+    whole = entry.Cell(config, traffic, seed=2**31 + 9)
+    c = entry.Cell(config, traffic, seed=2**31 + 9, traced=True)
+    n = runs or len(whole.grids)
+    assert len(c.grids) == len(c.points) == n
+    for (arch, tr), (want_arch, want) in zip(c.points, whole.points):
+        assert arch == want_arch and all(
+            np.array_equal(a, b) for a, b in zip(tr, want))
+    assert c.run_requests == whole.run_requests[:n]
+    assert c.requests_per_unit == sum(whole.run_requests[:n])
+    checks, failed = c.check([c.unit()], config["limits"])
+    assert failed == 0
+    for name, (value, limit) in checks.items():
+        assert value <= limit, name
