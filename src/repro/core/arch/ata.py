@@ -10,6 +10,7 @@ from __future__ import annotations
 import dataclasses
 from typing import ClassVar
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import tagarray
@@ -22,6 +23,7 @@ from repro.core.probe import fused_probe_rank
 class AtaPolicy(ArchPolicy):
     name: str = "ata"
     fills_own_core: ClassVar[bool] = True
+    sectored: ClassVar[bool] = True
 
     @property
     def stack_key(self) -> str:
@@ -80,6 +82,15 @@ class AtaPolicy(ArchPolicy):
                                 geom.lat_l1 + float(TAG_CHECK), l1_time)
         l1 = tagarray.touch_rows(l1, set_idx, way, t, local_hit,
                                  set_dirty=reqs.is_write)
+        if pr.need is None:
+            noc_flits = jnp.sum(remote_ok) * geom.flits_per_line
+            req_flits = remote_ok * (geom.flits_per_line * 1.0)
+        else:
+            # a peer sends the sectors the requester needs
+            req_flits = (jnp.where(remote_ok,
+                                   jax.lax.population_count(pr.need), 0)
+                         * (geom.flits_per_line / geom.sectors_per_line))
+            noc_flits = jnp.sum(req_flits)
         return L1Outcome(
             l1=l1,
             served=served,
@@ -91,9 +102,11 @@ class AtaPolicy(ArchPolicy):
             fill_set=set_idx,
             local_hits=local_hits,
             remote_hits=remote_ok,
-            noc_flits=jnp.sum(remote_ok) * geom.flits_per_line,
+            noc_flits=noc_flits,
             # only known remote hits put flits on the interconnect —
             # the tag-side filtering that is the paper's core win
             noc_src=jnp.where(remote_ok, src_cache, reqs.core),
-            noc_req_flits=remote_ok * (geom.flits_per_line * 1.0),
+            noc_req_flits=req_flits,
+            need=pr.need,
+            sector_misses=pr.sector_miss,
         )

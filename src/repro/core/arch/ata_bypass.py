@@ -18,6 +18,7 @@ sradv1), because skipped fills also skip dirty write-backs.
 from __future__ import annotations
 
 import dataclasses
+from typing import ClassVar
 
 import jax.numpy as jnp
 
@@ -30,6 +31,7 @@ from repro.core.geometry import GpuGeometry
 @dataclasses.dataclass(frozen=True)
 class AtaBypassPolicy(AtaPolicy):
     name: str = "ata_bypass"
+    sectored: ClassVar[bool] = False   # whole lines only
 
     def l1_stage(self, geom: GpuGeometry, l1: tagarray.TagState,
                  reqs: RequestBatch, t, *,

@@ -47,6 +47,9 @@ class RequestBatch(NamedTuple):
     set_idx: jnp.ndarray     # (R,) int32 local L1 set of addr
     bank: jnp.ndarray        # (R,) int32 local L1 bank of addr
     peers: jnp.ndarray       # (R, G) int32 cache ids of the whole cluster
+    #: (R,) int32 sectors the request asks for on a sectored geometry;
+    #: None where lines are whole
+    sect: Optional[jnp.ndarray] = None
 
     @property
     def n_requests(self) -> int:
@@ -80,6 +83,12 @@ class L1Outcome(NamedTuple):
     #: ``remote_hits * flits_per_line``. L2/write-back traffic rides
     #: the memory-side network and is *not* included here.
     noc_req_flits: Optional[jnp.ndarray] = None
+    #: Sectored geometries only (None where lines are whole): (R,) int32
+    #: sectors each request needs from a peer or the L2 and fills into
+    #: its L1 (its requested sectors the own L1 lacks), and (R,) bool
+    #: tag hits in the own L1 that lack a requested sector.
+    need: Optional[jnp.ndarray] = None
+    sector_misses: Optional[jnp.ndarray] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -102,8 +111,15 @@ class ArchPolicy:
     always ``reqs.core``, the requester's own L1: the shared fill stage
     then updates the L1 with ``tagarray.fill_rows`` instead of a
     scatter. A dataflow property of the class, not a knob.
+
+    ``sectored`` declares that the policy models sectored lines
+    (``GpuGeometry.sectors_per_line > 1``): a hit needs every requested
+    sector (``RequestBatch.sect``) and the outcome reports ``need`` and
+    ``sector_misses``. The simulator refuses a sectored geometry for
+    any other policy.
     """
     fills_own_core: ClassVar[bool] = False
+    sectored: ClassVar[bool] = False
 
     name: str
     replacement: ReplacementPolicy = ReplacementPolicy.LRU

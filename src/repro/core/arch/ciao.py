@@ -28,6 +28,7 @@ so (private, ciao) grids compile one executable.
 from __future__ import annotations
 
 import dataclasses
+from typing import ClassVar
 
 import jax.numpy as jnp
 
@@ -40,6 +41,7 @@ from repro.core.geometry import GpuGeometry
 @dataclasses.dataclass(frozen=True)
 class CiaoPolicy(PrivatePolicy):
     name: str = "ciao"
+    sectored: ClassVar[bool] = False   # whole lines only
     track_thrash: bool = True
     thrash_threshold: int = 4    # counter level that marks a lane thrashing
     thrash_decay: int = 1        # per-round counter decay

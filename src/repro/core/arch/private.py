@@ -4,6 +4,7 @@ from __future__ import annotations
 import dataclasses
 from typing import ClassVar
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import tagarray
@@ -15,6 +16,7 @@ from repro.core.geometry import GpuGeometry
 class PrivatePolicy(ArchPolicy):
     name: str = "private"
     fills_own_core: ClassVar[bool] = True
+    sectored: ClassVar[bool] = True
 
     def l1_stage(self, geom: GpuGeometry, l1: tagarray.TagState,
                  reqs: RequestBatch, t, *,
@@ -23,6 +25,15 @@ class PrivatePolicy(ArchPolicy):
         R = reqs.n_requests
         hit, way, _ = tagarray.probe(l1, reqs.core, reqs.set_idx, reqs.addr,
                                      policy=self.replacement)
+        need = sector_misses = None
+        if reqs.sect is not None:
+            # a hit needs every requested sector of the line
+            with jax.named_scope(tagarray.SECTOR_SCOPE):
+                need = jnp.where(
+                    hit, reqs.sect & ~tagarray.sectors_at(
+                        l1, reqs.core, reqs.set_idx, way), reqs.sect)
+                sector_misses = hit & (need != 0)
+                hit = need == 0
         l1 = tagarray.touch_rows(l1, reqs.set_idx, way, t, hit,
                                  set_dirty=reqs.is_write)
         return L1Outcome(
@@ -37,4 +48,6 @@ class PrivatePolicy(ArchPolicy):
             local_hits=hit,
             remote_hits=jnp.zeros((R,), bool),
             noc_flits=0.0,
+            need=need,
+            sector_misses=sector_misses,
         )

@@ -28,6 +28,7 @@ plus this variant compiles into one stacked executable.
 from __future__ import annotations
 
 import dataclasses
+from typing import ClassVar
 
 import jax.numpy as jnp
 
@@ -40,6 +41,7 @@ from repro.core.geometry import GpuGeometry
 @dataclasses.dataclass(frozen=True)
 class VictimPolicy(AtaPolicy):
     name: str = "victim"
+    sectored: ClassVar[bool] = False   # whole lines only
     victim_ways: int = 8
 
     def _victim_prefilter(self, l1: tagarray.TagState, reqs: RequestBatch):

@@ -45,6 +45,10 @@ class GpuGeometry:
     l2_parts: int = 24
     l2_sets: int = 64
     l2_ways: int = 16
+    # Sectors per line: tags per 128B line, data moved and tracked in
+    # line/sectors_per_line pieces (Volta and later; GPGPU-Sim 4). 1 is
+    # the paper's whole-line model.
+    sectors_per_line: int = 1
 
     # --- uncontended latencies (cycles) ------------------------------------
     lat_l1: int = 32
@@ -86,7 +90,8 @@ PAPER_GEOMETRY = GpuGeometry()
 
 #: Fields that fix array shapes / routing arithmetic (static under jit).
 GEOM_STRUCTURE_FIELDS = ("n_cores", "cluster_size", "l1_sets", "l1_ways",
-                         "l1_banks", "l2_parts", "l2_sets", "l2_ways")
+                         "l1_banks", "l2_parts", "l2_sets", "l2_ways",
+                         "sectors_per_line")
 
 #: Timing fields that only enter arithmetic (traceable under jit).
 GEOM_SCALAR_FIELDS = ("lat_l1", "lat_xbar", "lat_home", "lat_l2",
@@ -107,6 +112,7 @@ class GeomStructure(NamedTuple):
     l2_parts: int
     l2_sets: int
     l2_ways: int
+    sectors_per_line: int = 1
 
     @property
     def n_clusters(self) -> int:

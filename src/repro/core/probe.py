@@ -36,7 +36,10 @@ switches between same-dataflow models inside one executable):
     exact-equivalence artifact tier-1 tests pin against ``lax``.
 
 All four return identical integers/booleans (tier-1 tested), so every
-committed golden is backend-invariant.
+committed golden is backend-invariant. The ``lax`` paths also model
+sectored lines (``RequestBatch.sect``): a hit, local or remote, needs
+every requested sector. The Pallas paths compare whole lines and refuse
+sectored requests.
 
 Every backend runs inside the ``jax.named_scope`` :data:`PROBE_SCOPE`
 (nested in the round's ``l1`` stage scope), and the Pallas kernel
@@ -90,6 +93,11 @@ class ProbeRank(NamedTuple):
     src_cache: jnp.ndarray   # int32 — serving peer cache id
     prank: jnp.ndarray       # int32 — position at the serving port
     psize: jnp.ndarray       # int32 — contention group size
+    #: sectored geometries only (``lax`` paths): sectors the request
+    #: needs from a peer or the L2, and own-array tag hits that lack a
+    #: requested sector
+    need: Optional[jnp.ndarray] = None
+    sector_miss: Optional[jnp.ndarray] = None
 
 
 def _lax_path(geom, l1: tagarray.TagState, reqs, pre_served,
@@ -98,6 +106,18 @@ def _lax_path(geom, l1: tagarray.TagState, reqs, pre_served,
     hits, ways, dirt = tagarray.probe_many(l1, reqs.peers, set_idx, addr)
     is_self = (jnp.arange(geom.cluster_size)[None, :]
                == reqs.self_slot[:, None])
+    need = sector_miss = None
+    if reqs.sect is not None:
+        # a hit, local or remote, needs every requested sector
+        with jax.named_scope(tagarray.SECTOR_SCOPE):
+            lacking = reqs.sect[:, None] & ~tagarray.sectors_at(
+                l1, reqs.peers, set_idx[:, None], ways)
+            own_tag_hit = (hits & is_self).any(axis=-1)
+            own_lacking = jnp.take_along_axis(
+                lacking, reqs.self_slot[:, None], axis=1)[:, 0]
+            need = jnp.where(own_tag_hit, own_lacking, reqs.sect)
+            sector_miss = own_tag_hit & (own_lacking != 0)
+            hits = hits & (lacking == 0)
     local_hit = (hits & is_self).any(axis=-1)
     hit_way = jnp.take_along_axis(ways, reqs.self_slot[:, None],
                                   axis=1)[:, 0]
@@ -123,12 +143,17 @@ def _lax_path(geom, l1: tagarray.TagState, reqs, pre_served,
         remote_ok = remote_ok & ~pre_served
     prank, psize = group_rank(src_cache, remote_ok, geom.n_cores)
     return ProbeRank(local_hit, touch_way, remote_ok, src_cache,
-                     prank, psize)
+                     prank, psize, need, sector_miss)
 
 
 def _pallas_path(geom, l1: tagarray.TagState, reqs, pre_served,
                  interpret: bool) -> ProbeRank:
     from repro.kernels.ata_probe_rank import ata_probe_rank
+    if reqs.sect is not None:
+        raise ValueError(
+            "the pallas probe backends compare whole lines; sectored "
+            "geometries (sectors_per_line > 1) take 'lax' or "
+            "'lax_unfused'")
     deny = reqs.is_write
     if pre_served is not None:
         deny = deny | pre_served

@@ -98,6 +98,10 @@ class _TraceBase(NamedTuple):
     #: (C,) int32 app id per core (multi-tenant mixes), or None — the
     #: canonical single-app trace (all cores app 0)
     core_app: Optional[np.ndarray] = None
+    #: (T, C, m) uint8 sectors each request asks for, for geometries
+    #: with ``sectors_per_line > 1``; None = every request wants the
+    #: whole line
+    sect: Optional[np.ndarray] = None
 
 
 class Trace(_TraceBase):
@@ -118,11 +122,15 @@ class Trace(_TraceBase):
       their executable regardless of how they were built;
     * ``core_app`` ids must be dense (every id in ``0..n_apps-1``
       assigned to at least one core); a single-app assignment collapses
-      to ``None``, the canonical solo form.
+      to ``None``, the canonical solo form;
+    * ``sect``, where given, must be non-zero integer masks below 256
+      in ``addr``'s shape (stored as uint8); the geometry it runs on
+      bounds them further (:func:`check_sectors`).
     """
     __slots__ = ()
 
-    def __new__(cls, addr, is_write, insn_per_req, core_app=None):
+    def __new__(cls, addr, is_write, insn_per_req, core_app=None,
+                sect=None):
         addr = np.asarray(addr)
         if addr.dtype != np.int32:
             raise ValueError(
@@ -169,7 +177,22 @@ class Trace(_TraceBase):
                     "Trace.core_app ids must be dense 0..n_apps-1 "
                     f"(every app owns at least one core), got {ids.tolist()}")
             core_app = None if ids.size == 1 else ca.astype(np.int32)
-        return super().__new__(cls, addr, is_write, insn_per_req, core_app)
+        if sect is not None:
+            sect = np.asarray(sect)
+            if not np.issubdtype(sect.dtype, np.integer):
+                raise ValueError(
+                    f"Trace.sect must be integer masks, got {sect.dtype}")
+            if sect.shape != addr.shape:
+                raise ValueError(
+                    f"Trace.sect shape {sect.shape} != addr shape "
+                    f"{addr.shape}")
+            if sect.size and (sect.min() < 1 or sect.max() > 255):
+                raise ValueError(
+                    "Trace.sect masks must lie in 1..255, got "
+                    f"[{sect.min()}, {sect.max()}]")
+            sect = sect.astype(np.uint8)
+        return super().__new__(cls, addr, is_write, insn_per_req, core_app,
+                               sect)
 
     def _replace(self, **kwds) -> "Trace":
         """Route through ``__new__`` so replaced traces re-validate.
@@ -299,6 +322,12 @@ class SimResult(NamedTuple):
     per_app: Tuple[AppStats, ...] = ()
     #: interconnect metrics (all-zero under the default ``ideal`` model)
     noc: NocStats = _ZERO_NOC
+    #: sectored geometries only (0 where ``sectors_per_line == 1``):
+    #: L1 tag hits in the requester's own array lacking a requested
+    #: sector, L2 tag hits lacking a needed sector, sectors from DRAM
+    l1_sector_misses: float = 0.0
+    l2_sector_misses: float = 0.0
+    dram_sectors: float = 0.0
 
 
 def _l1_state(geom, policies: Sequence[ArchPolicy]) -> tagarray.TagState:
@@ -313,11 +342,13 @@ def _l1_state(geom, policies: Sequence[ArchPolicy]) -> tagarray.TagState:
     thrash = geom.n_cores if any(p.track_thrash for p in policies) else 0
     return tagarray.init_tag_state(geom.n_cores, geom.l1_sets,
                                    geom.l1_ways, victim_ways=victim,
-                                   thrash_lanes=thrash)
+                                   thrash_lanes=thrash,
+                                   sectors=geom.sectors_per_line)
 
 
 def _l2_state(geom) -> tagarray.TagState:
-    return tagarray.init_tag_state(geom.l2_parts, geom.l2_sets, geom.l2_ways)
+    return tagarray.init_tag_state(geom.l2_parts, geom.l2_sets, geom.l2_ways,
+                                   sectors=geom.sectors_per_line)
 
 
 def _noc_state(geom, models: Sequence[NocModel]):
@@ -331,12 +362,14 @@ def _noc_state(geom, models: Sequence[NocModel]):
     return init_noc_state(max(m.n_links(geom) for m in models))
 
 
-def _request_batch(geom, addr, is_write) -> RequestBatch:
+def _request_batch(geom, addr, is_write, sect=None) -> RequestBatch:
     """Flatten one round's (C, m) requests and derive routing indices."""
     C, m = addr.shape
     R = C * m
     addr = addr.reshape(R)
     is_write = is_write.reshape(R)
+    if sect is not None:
+        sect = sect.reshape(R).astype(jnp.int32)
     core = jnp.repeat(jnp.arange(C, dtype=jnp.int32), m)
     cluster = core // geom.cluster_size
     self_slot = core % geom.cluster_size
@@ -346,7 +379,7 @@ def _request_batch(geom, addr, is_write) -> RequestBatch:
              + jnp.arange(geom.cluster_size, dtype=jnp.int32)[None, :])
     return RequestBatch(addr=addr, is_write=is_write, core=core,
                         cluster=cluster, self_slot=self_slot,
-                        set_idx=set_idx, bank=bank, peers=peers)
+                        set_idx=set_idx, bank=bank, peers=peers, sect=sect)
 
 
 def _round(policy: ArchPolicy, nocs: Sequence[NocModel], noc_idx,
@@ -354,7 +387,8 @@ def _round(policy: ArchPolicy, nocs: Sequence[NocModel], noc_idx,
            probe_backend: str = "lax",
            telemetry: Optional[TelemetryConfig] = None):
     """One simulation round. state=(l1, l2, noc, t, stats);
-    xs=(addr, is_write).
+    xs=(addr, is_write), plus the (C, m) sector masks on a sectored
+    geometry.
 
     ``geom`` is a :class:`TracedGeometry` view (or a concrete
     ``GpuGeometry``): structure fields are static, timing scalars may be
@@ -369,13 +403,22 @@ def _round(policy: ArchPolicy, nocs: Sequence[NocModel], noc_idx,
 
     Each stage runs under its :data:`ROUND_STAGES` named scope; a scope
     changes only op metadata, never the computation.
+
+    On a sectored geometry (``S = sectors_per_line > 1``) a hit needs
+    every requested sector: the L1 policy reports each request's
+    ``need`` (its sectors the own L1 lacks), the L2 serves it where its
+    way holds them all and fetches the rest from DRAM, and each
+    transfer of ``k`` sectors costs ``k * flits_per_line / S`` flits.
     """
     l1, l2, noc, t, stats = state
-    addr, is_write = xs                      # (C, m)
+    addr, is_write, *sect = xs               # (C, m)
     C, m = addr.shape
-    reqs = _request_batch(geom, addr, is_write)
+    reqs = _request_batch(geom, addr, is_write, *sect)
     addr = reqs.addr                         # (R,) flattened
     R = reqs.n_requests
+    sectored = reqs.sect is not None
+    if sectored:
+        sector_flits = geom.flits_per_line / geom.sectors_per_line
 
     # ---- L1 policy stage (the only architecture-specific part) ------------
     with jax.named_scope(_L1):
@@ -390,16 +433,35 @@ def _round(policy: ArchPolicy, nocs: Sequence[NocModel], noc_idx,
         l2_part = (addr % geom.l2_parts).astype(jnp.int32)
         l2_set = ((addr // geom.l2_parts) % geom.l2_sets).astype(jnp.int32)
         l2_hit, l2_way, _ = tagarray.probe(l2, l2_part, l2_set, addr)
+        if sectored:
+            with jax.named_scope(tagarray.SECTOR_SCOPE):
+                l2_tag_hit = l2_hit
+                fetch = jnp.where(
+                    l2_tag_hit,
+                    out.need & ~tagarray.sectors_at(l2, l2_part, l2_set,
+                                                    l2_way),
+                    out.need)
+                l2_hit = fetch == 0
         l2_rank, l2_size = group_rank(l2_part, go_l2, geom.l2_parts)
         l2_time = (geom.lat_l2 + l2_rank.astype(jnp.float32) * geom.svc_l2
                    + jnp.where(l2_hit, 0.0, geom.lat_dram * 1.0))
         occupancy = jnp.maximum(
             occupancy,
             jnp.where(go_l2, l2_size.astype(jnp.float32) * geom.svc_l2, 0.0))
-        l2 = tagarray.touch(l2, l2_part, l2_set, l2_way, t, go_l2 & l2_hit)
-        l2, _ = tagarray.fill(l2, l2_part, l2_set, l2_way, addr, t,
-                              go_l2 & ~l2_hit)
-        noc_flits = noc_flits + jnp.sum(go_l2) * geom.flits_per_line
+        if sectored:
+            l2 = tagarray.touch(l2, l2_part, l2_set, l2_way, t,
+                                go_l2 & l2_tag_hit, sect=fetch)
+            l2, _ = tagarray.fill(l2, l2_part, l2_set, l2_way, addr, t,
+                                  go_l2 & ~l2_tag_hit, sect=out.need)
+            noc_flits = noc_flits + jnp.sum(jnp.where(
+                go_l2, jax.lax.population_count(out.need), 0)
+            ) * sector_flits
+        else:
+            l2 = tagarray.touch(l2, l2_part, l2_set, l2_way, t,
+                                go_l2 & l2_hit)
+            l2, _ = tagarray.fill(l2, l2_part, l2_set, l2_way, addr, t,
+                                  go_l2 & ~l2_hit)
+            noc_flits = noc_flits + jnp.sum(go_l2) * geom.flits_per_line
 
     # ---- L1 fill on L2 return (and on remote fetch: replicate locally) ----
     with jax.named_scope(_FILL):
@@ -414,11 +476,17 @@ def _round(policy: ArchPolicy, nocs: Sequence[NocModel], noc_idx,
                     f"{policy.name!r} declares fills_own_core but its "
                     "fill_cache is not reqs.core")
             l1, wb = tagarray.fill_rows(l1, out.fill_set, fway, addr, t,
-                                        fill_mask, dirty=reqs.is_write)
+                                        fill_mask, dirty=reqs.is_write,
+                                        sect=out.need)
         else:
             l1, wb = tagarray.fill(l1, out.fill_cache, out.fill_set, fway,
                                    addr, t, fill_mask, dirty=reqs.is_write)
-        noc_flits = noc_flits + jnp.sum(wb) * geom.flits_per_line
+        if sectored:
+            # write-backs carry the evicted line's valid sectors
+            noc_flits = noc_flits + jnp.sum(
+                jax.lax.population_count(wb)) * sector_flits
+        else:
+            noc_flits = noc_flits + jnp.sum(wb) * geom.flits_per_line
 
     # ---- NoC stage: remote-probe/remote-data flits through the active
     # interconnect model (repro.core.noc). The policies' own memoryless
@@ -490,6 +558,15 @@ def _round(policy: ArchPolicy, nocs: Sequence[NocModel], noc_idx,
             "app_lat_n": stats["app_lat_n"]
             .at[core_app].add(all_served.astype(f32)),
         }
+        if sectored:
+            prev = state[4]
+            stats["l1_sector_misses"] = (prev["l1_sector_misses"]
+                                         + jnp.sum(out.sector_misses))
+            stats["l2_sector_misses"] = (prev["l2_sector_misses"]
+                                         + jnp.sum(go_l2 & l2_tag_hit
+                                                   & ~l2_hit))
+            stats["dram_sectors"] = prev["dram_sectors"] + jnp.sum(
+                jnp.where(go_l2, jax.lax.population_count(fetch), 0))
         if telemetry is not None and telemetry.histograms:
             # log2-bucketed L1-complete latency histogram over served
             # loads (unserved cores contribute an add of 0 — a no-op).
@@ -510,6 +587,8 @@ def _init_stats(geom, n_apps: int = 1,
              "dram": z, "noc_flits": z,
              "app_local": app, "app_remote": app,
              "app_lat_sum": app, "app_lat_n": app}
+    if geom.sectors_per_line > 1:
+        stats.update(l1_sector_misses=z, l2_sector_misses=z, dram_sectors=z)
     if telemetry is not None and telemetry.histograms:
         stats["lat_hist"] = jnp.zeros((telemetry.sim_hist_bins,),
                                       jnp.int32)
@@ -528,7 +607,8 @@ def _sim_core(archs: Tuple[str, ...], nocs: Tuple[str, ...], point_arrays,
     ``nocs`` is the stacked interconnect-model group, selected by the
     traced ``noc_idx`` the same way (an inner switch over the NoC
     stage). ``point_arrays = (addr, is_write, insn_per_req, core_app,
-    scalars, policy_idx, noc_idx)`` — everything but ``archs``/
+    scalars, policy_idx, noc_idx)``, with the (T, C, m) sector masks
+    last on a sectored geometry — everything but ``archs``/
     ``nocs``/``structure``/``n_apps``/``probe_backend`` is traced, so
     one executable serves whole (policy, NoC, timing-geometry, trace)
     grids; ``n_apps`` sizes the per-app attribution accumulators
@@ -549,7 +629,8 @@ def _sim_core(archs: Tuple[str, ...], nocs: Tuple[str, ...], point_arrays,
     default executables byte-identical.
     """
     addr, is_write, insn_per_req, core_app, scalars, policy_idx, \
-        noc_idx = point_arrays
+        noc_idx, *sect = point_arrays
+    xs = (addr, is_write, *sect)
     geom = TracedGeometry(structure, scalars)
     policies = [get_arch(a) for a in archs]
     noc_models = [get_noc(n) for n in nocs]
@@ -567,14 +648,12 @@ def _sim_core(archs: Tuple[str, ...], nocs: Tuple[str, ...], point_arrays,
         def step(carry, xs):
             return jax.lax.switch(policy_idx, steps, carry, xs)
     if telemetry is None:
-        (l1, l2, noc, t, stats), _ = jax.lax.scan(step, state,
-                                                  (addr, is_write))
+        (l1, l2, noc, t, stats), _ = jax.lax.scan(step, state, xs)
         return {**stats, "noc": noc}
 
     T = addr.shape[0]
     W = telemetry.window_for(T)
-    xs = (addr.reshape((T // W, W) + addr.shape[1:]),
-          is_write.reshape((T // W, W) + is_write.shape[1:]))
+    xs = tuple(x.reshape((T // W, W) + x.shape[1:]) for x in xs)
 
     def window_step(carry, xs_w):
         carry, _ = jax.lax.scan(step, carry, xs_w)
@@ -600,8 +679,20 @@ _simulate_batch = jax.jit(
     static_argnums=(0, 1, 3, 4, 5))
 
 
-def _trace_arrays(trace: Trace):
-    """One trace's traced leaves: (addr, is_write, insn, core_app)."""
+def sector_masks(trace: Trace, sectors: int) -> Tuple[np.ndarray, ...]:
+    """The trace's sector masks as a sectored geometry's scan takes them
+    (whole-line masks where the trace has none); ``()`` for ``sectors
+    == 1``, whose programs take no mask."""
+    if sectors == 1:
+        return ()
+    if trace.sect is None:
+        return (np.full(trace.addr.shape, (1 << sectors) - 1, np.uint8),)
+    return (trace.sect,)
+
+
+def _trace_arrays(trace: Trace, sectors: int = 1):
+    """One trace's traced leaves: (addr, is_write, insn, core_app), and
+    its sector masks on a sectored geometry."""
     addr = jnp.asarray(trace.addr, jnp.int32)
     is_write = jnp.asarray(trace.is_write, bool)
     if np.ndim(trace.insn_per_req) == 0:
@@ -609,14 +700,16 @@ def _trace_arrays(trace: Trace):
     else:
         insn = jnp.asarray(trace.insn_per_req, jnp.float32)
     core_app = jnp.asarray(trace.core_app_ids, jnp.int32)
-    return addr, is_write, insn, core_app
+    sect = tuple(jnp.asarray(x, jnp.uint8)
+                 for x in sector_masks(trace, sectors))
+    return (addr, is_write, insn, core_app) + sect
 
 
 def _point_arrays(trace_like, scalars, policy_idx=0, noc_idx=0):
     """Pack one grid point's traced leaves for :func:`_sim_core`."""
-    addr, is_write, insn, core_app = trace_like
+    addr, is_write, insn, core_app, *sect = trace_like
     return (addr, is_write, insn, core_app, scalars,
-            jnp.int32(policy_idx), jnp.int32(noc_idx))
+            jnp.int32(policy_idx), jnp.int32(noc_idx), *sect)
 
 
 def round_signature(group: Tuple[str, ...], arch: str,
@@ -649,8 +742,10 @@ def round_signature(group: Tuple[str, ...], arch: str,
     noc_models = [get_noc(n) for n in noc_group]
     scalars = GeomScalars(*(jax.ShapeDtypeStruct((), jnp.float32)
                             for _ in GEOM_SCALAR_FIELDS))
+    sect = ((jax.ShapeDtypeStruct((C, m), jnp.uint8),)
+            if structure.sectors_per_line > 1 else ())
 
-    def one_round(scalars, addr, is_write, insn, core_app):
+    def one_round(scalars, addr, is_write, insn, core_app, *sect):
         geom = TracedGeometry(structure, scalars)
         state = (_l1_state(geom, policies), _l2_state(geom),
                  _noc_state(geom, noc_models), jnp.int32(0),
@@ -661,7 +756,7 @@ def round_signature(group: Tuple[str, ...], arch: str,
         # an opaque lax.switch failure inside the compiled executable
         new_state, _ = _round(get_arch(arch), [get_noc(noc)], jnp.int32(0),
                               geom, insn, core_app,
-                              state, (addr, is_write),
+                              state, (addr, is_write, *sect),
                               probe_backend=probe_backend)
         return new_state
 
@@ -669,7 +764,7 @@ def round_signature(group: Tuple[str, ...], arch: str,
                          jax.ShapeDtypeStruct((C, m), jnp.int32),
                          jax.ShapeDtypeStruct((C, m), jnp.bool_),
                          jax.ShapeDtypeStruct(insn_shape, jnp.float32),
-                         jax.ShapeDtypeStruct((C,), jnp.int32))
+                         jax.ShapeDtypeStruct((C,), jnp.int32), *sect)
     leaves, treedef = jax.tree.flatten(out)
     return treedef, tuple((l.shape, str(l.dtype)) for l in leaves)
 
@@ -719,6 +814,9 @@ def _summarize(stats, trace: Trace) -> SimResult:
             l1_lat_sum=float(stats["app_lat_sum"][a]),
             l1_lat_n=float(stats["app_lat_n"][a])))
 
+    sector = {k: float(stats[k]) for k in
+              ("l1_sector_misses", "l2_sector_misses", "dram_sectors")
+              if k in stats}
     return SimResult(
         ipc=instructions / cycles,
         # NaN when no load was ever fully served inside the L1 complex
@@ -735,6 +833,7 @@ def _summarize(stats, trace: Trace) -> SimResult:
         instructions=instructions,
         per_app=tuple(per_app),
         noc=noc_block,
+        **sector,
     )
 
 
@@ -746,6 +845,46 @@ def _check_arch(arch: str) -> None:
 def _check_noc(noc: str) -> None:
     if noc not in registered_nocs():
         raise ValueError(f"noc must be one of {registered_nocs()}")
+
+
+#: Probe backends that lower the sector compares (``repro.core.probe``).
+SECTOR_PROBE_BACKENDS = ("lax", "lax_unfused")
+
+
+def check_sectors(arch: str, sectors: int, trace: Trace,
+                  probe_backend: str = "lax") -> None:
+    """Refuse a point the sectored model does not cover.
+
+    ``sectors_per_line`` must lie in 1..8. A sector mask needs a
+    sectored geometry, and its masks must name sectors of the line.
+    Sectored geometries run the policies that model sectors
+    (``ArchPolicy.sectored``, under LRU replacement) on a backend
+    that lowers the sector compares.
+    """
+    if not 1 <= sectors <= 8:
+        raise ValueError(
+            f"sectors_per_line must lie in 1..8, got {sectors}")
+    if trace.sect is not None:
+        if sectors == 1:
+            raise ValueError(
+                "the trace carries sector masks but the geometry has "
+                "whole lines (sectors_per_line = 1)")
+        if trace.sect.size and int(trace.sect.max()) >= 1 << sectors:
+            raise ValueError(
+                f"sector masks up to {int(trace.sect.max())} name "
+                f"sectors beyond the line's {sectors}")
+    if sectors == 1:
+        return
+    policy = get_arch(arch)
+    if not (policy.sectored
+            and policy.replacement is tagarray.ReplacementPolicy.LRU):
+        raise ValueError(
+            f"architecture {arch!r} does not model sectored lines "
+            f"(sectors_per_line = {sectors}); use private or ata")
+    if probe_backend not in SECTOR_PROBE_BACKENDS:
+        raise ValueError(
+            f"probe_backend {probe_backend!r} does not lower sectored "
+            f"lines; use one of {SECTOR_PROBE_BACKENDS}")
 
 
 def trace_kind(trace: Trace) -> tuple:
@@ -780,11 +919,13 @@ def simulate(arch: str, trace: Trace,
     _check_arch(arch)
     _check_noc(noc)
     _check_probe_backend(probe_backend)
+    check_sectors(arch, geom.sectors_per_line, trace, probe_backend)
     if telemetry is not None:
         telemetry.window_for(trace.addr.shape[0])
     structure, scalars = split_geometry(geom)
     stats = jax.device_get(_simulate(
-        (arch,), (noc,), _point_arrays(_trace_arrays(trace), scalars),
+        (arch,), (noc,),
+        _point_arrays(_trace_arrays(trace, geom.sectors_per_line), scalars),
         structure, trace.n_apps, probe_backend, telemetry))
     if telemetry is None:
         return _summarize(stats, trace)
@@ -823,6 +964,8 @@ def simulate_batch(arch: str, traces: Sequence[Trace],
             f"simulate_batch needs same-shape, same-kind traces "
             f"((T, C, m), insn shape, n_apps), got {sorted(kinds)}; use "
             "simulate_many for mixed kinds")
+    for t in traces:
+        check_sectors(arch, geom.sectors_per_line, t, probe_backend)
     structure, scalars = split_geometry(geom)
     B = len(traces)
     n_apps = traces[0].n_apps
@@ -835,9 +978,12 @@ def simulate_batch(arch: str, traces: Sequence[Trace],
                            jnp.float32)
     core_app = jnp.asarray(np.stack([t.core_app_ids for t in traces]),
                            jnp.int32)
+    sect = tuple(jnp.asarray(np.stack(x), jnp.uint8) for x in zip(
+        *(sector_masks(t, geom.sectors_per_line) for t in traces)))
     batched = ((addr, is_write, insn, core_app,
                 jax.tree.map(lambda s: jnp.broadcast_to(s, (B,)), scalars),
-                jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32)))
+                jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32))
+               + sect)
     stats = jax.device_get(_simulate_batch((arch,), (noc,), batched,
                                            structure, n_apps,
                                            probe_backend))

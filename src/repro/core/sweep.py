@@ -71,8 +71,8 @@ import numpy as np
 from repro.core.geometry import (GeomStructure, GpuGeometry, PAPER_GEOMETRY,
                                  geom_structure, split_geometry)
 from repro.core.simulator import (SimResult, Trace, _check_arch, _check_noc,
-                                  _sim_core, _summarize, round_signature,
-                                  trace_kind)
+                                  _sim_core, _summarize, check_sectors,
+                                  round_signature, sector_masks, trace_kind)
 from repro.core.telemetry import TelemetryConfig
 from repro.core.arch import get_arch, registered_archs
 from repro.core.noc import get_noc, registered_nocs
@@ -256,9 +256,11 @@ def _signature(group: Tuple[str, ...], arch: str, structure: GeomStructure,
 
 
 def _bucket_arrays(pts: Sequence[SweepPoint], kind: tuple, split,
-                   group: Tuple[str, ...], noc_group: Tuple[str, ...]):
+                   group: Tuple[str, ...], noc_group: Tuple[str, ...],
+                   sectors: int = 1):
     """One bucket's stacked point arrays, on the device, in the order
-    :func:`repro.core.simulator._sim_core` takes them."""
+    :func:`repro.core.simulator._sim_core` takes them: the sector masks
+    last, on a sectored geometry."""
     _, insn_shape, _ = kind
     addr = jnp.asarray(np.stack([p.trace.addr for p in pts]), jnp.int32)
     is_write = jnp.asarray(
@@ -278,7 +280,10 @@ def _bucket_arrays(pts: Sequence[SweepPoint], kind: tuple, split,
         [group.index(p.arch) for p in pts], jnp.int32)
     noc_idx = jnp.asarray(
         [noc_group.index(p.noc) for p in pts], jnp.int32)
-    return (addr, is_write, insn, core_app, scalars, policy_idx, noc_idx)
+    sect = tuple(jnp.asarray(np.stack(x), jnp.uint8) for x in zip(
+        *(sector_masks(p.trace, sectors) for p in pts)))
+    return (addr, is_write, insn, core_app, scalars, policy_idx,
+            noc_idx) + sect
 
 
 class SweepGrid:
@@ -324,6 +329,8 @@ class SweepGrid:
             if id(p.geom) not in seen:
                 seen.add(id(p.geom))
                 _validate_geom(p.geom)
+            check_sectors(p.arch, p.geom.sectors_per_line, p.trace,
+                          p.probe_backend)
         self._validate_stacking()
 
     def _noc_group_of(self) -> Dict[str, Tuple[str, ...]]:
@@ -499,7 +506,7 @@ class SweepGrid:
                     rows = idxs + [idxs[-1]] * pad      # repeat last point
                     args = _bucket_arrays(
                         [self.points[i] for i in rows], kind, split,
-                        group, noc_group)
+                        group, noc_group, structure.sectors_per_line)
                 with jax.profiler.TraceAnnotation(_LAUNCH):
                     exec_key = (group, noc_group, structure, kind, backend,
                                 B + pad, D, telemetry)

@@ -17,6 +17,11 @@ shares one pytree structure and stacked sweep executables line up):
     vborn : (n_arrays, victim_ways) int32   install timestamp per entry
     thrash: (thrash_lanes,) int32           per-lane thrash counters
 
+and, for sectored lines (``sectors > 1``; absent otherwise, since even
+a zero-sized leaf changes the compiled programs of whole-line caches):
+
+    sect  : (n_arrays, n_sets, n_ways) int32   valid-sector bit mask
+
 Zero-sized extensions are exact no-ops: ``victim_probe`` returns all
 misses and ``victim_insert``/``victim_invalidate`` return the state
 unchanged, so architectures that ignore the extensions are bit-exact
@@ -44,6 +49,15 @@ write-back.) Within the masked-*in* requests, duplicate
 (array, set, way) targets still resolve last-writer-wins, matching a
 single-ported fill path.
 
+Sectors: a line's tag covers ``sectors`` sector bits. ``sectors_at``
+reads the mask of a way ``probe``/``probe_many`` found. With ``sect``
+given, ``fill_rows`` and ``fill`` apply their requests in order (slot
+order, or request order): a request whose target way holds its line at
+that moment ORs its sectors (and dirty bit) into it, any other replaces
+the way. ``touch`` ORs ``sect`` into the ways it refreshes. Duplicate
+targets of the scatter forms are resolved before the scatter, so every
+duplicate writes the same value and the scatter's order cannot matter.
+
 Row form: where every request writes into its *own* array — request
 ``j`` of row ``a`` into array ``a``, as the simulator's round orders
 each core's ``m`` requests — ``touch_rows``/``fill_rows`` apply the
@@ -62,9 +76,14 @@ from __future__ import annotations
 import enum
 from typing import Dict, Tuple
 
+import jax
 import jax.numpy as jnp
 
 TagState = Dict[str, jnp.ndarray]
+
+#: Named scope of the sector compares and merges, nested in the round's
+#: stage scopes (``repro.core.simulator``). The benchmark reads it.
+SECTOR_SCOPE = "sector"
 
 
 class ReplacementPolicy(enum.Enum):
@@ -82,9 +101,10 @@ class ReplacementPolicy(enum.Enum):
 
 
 def init_tag_state(n_arrays: int, n_sets: int, n_ways: int, *,
-                   victim_ways: int = 0, thrash_lanes: int = 0) -> TagState:
+                   victim_ways: int = 0, thrash_lanes: int = 0,
+                   sectors: int = 1) -> TagState:
     shape = (n_arrays, n_sets, n_ways)
-    return {
+    state = {
         "tags": jnp.zeros(shape, jnp.int32),
         "last": jnp.full(shape, -1, jnp.int32),
         "born": jnp.full(shape, -1, jnp.int32),
@@ -97,6 +117,20 @@ def init_tag_state(n_arrays: int, n_sets: int, n_ways: int, *,
         "vborn": jnp.full((n_arrays, victim_ways), -1, jnp.int32),
         "thrash": jnp.zeros((thrash_lanes,), jnp.int32),
     }
+    if sectors > 1:
+        state["sect"] = jnp.zeros(shape, jnp.int32)
+    return state
+
+
+def sectors_at(state: TagState, array_idx, set_idx, way) -> jnp.ndarray:
+    """Valid-sector mask of the ways ``probe`` or ``probe_many`` found
+    (indices broadcast); meaningful where the probe hit."""
+    return state["sect"][array_idx, set_idx, way]
+
+
+def _or_reduce(x: jnp.ndarray, axis: int) -> jnp.ndarray:
+    """Bitwise OR of an int32 array along ``axis``."""
+    return jax.lax.reduce(x, jnp.int32(0), jax.lax.bitwise_or, (axis,))
 
 
 def _select_victim(state: TagState, array_idx, set_idx, addr,
@@ -168,8 +202,9 @@ def _drop_unmasked(state: TagState, array_idx, mask) -> jnp.ndarray:
 
 
 def touch(state: TagState, array_idx, set_idx, way, now,
-          mask, *, set_dirty=None) -> TagState:
-    """Refresh LRU timestamp (and optionally dirty) for masked requests."""
+          mask, *, set_dirty=None, sect=None) -> TagState:
+    """Refresh LRU timestamp (and optionally dirty) for masked requests;
+    with ``sect``, OR each request's sectors into its way."""
     a = _drop_unmasked(state, array_idx, mask)
     last = state["last"].at[a, set_idx, way].max(now, mode="drop")
     out = dict(state, last=last)
@@ -177,18 +212,32 @@ def touch(state: TagState, array_idx, set_idx, way, now,
         ad = _drop_unmasked(state, array_idx, mask & set_dirty)
         out["dirty"] = state["dirty"].at[ad, set_idx, way].set(
             True, mode="drop")
+    if sect is not None:
+        with jax.named_scope(SECTOR_SCOPE):
+            flat = _flat_target(state, array_idx, set_idx, way)
+            same = (flat[:, None] == flat[None, :]) & mask[None, :]
+            merged = (_or_reduce(jnp.where(same, sect[None, :], 0), 1)
+                      | sectors_at(state, array_idx, set_idx, way))
+            out["sect"] = state["sect"].at[a, set_idx, way].set(
+                merged, mode="drop")
     return out
 
 
 def fill(state: TagState, array_idx, set_idx, way, addr, now,
-         mask, *, dirty=None) -> Tuple[TagState, jnp.ndarray]:
+         mask, *, dirty=None, sect=None) -> Tuple[TagState, jnp.ndarray]:
     """Install lines for masked requests; returns (state, evicted_dirty).
 
     Masked-out requests are dropped (see the scatter-mask convention in
     the module docstring); within the masked-in set, duplicate
     (array,set,way) targets resolve last-writer-wins, matching a
     single-ported fill path. ``evicted_dirty`` flags write-back traffic.
+    With ``sect``, see :func:`_fill_sectored`.
     """
+    if sect is not None:
+        if dirty is not None:
+            raise ValueError("sectored scatter fills install clean lines")
+        return _fill_sectored(state, array_idx, set_idx, way, addr, now,
+                              mask, sect)
     a = _drop_unmasked(state, array_idx, mask)
     # Reads use the caller's (always in-bounds) indices; the results are
     # masked, so masked-out lanes never contribute.
@@ -207,6 +256,68 @@ def fill(state: TagState, array_idx, set_idx, way, addr, now,
     # counters) ride through untouched.
     return dict(state, tags=tags, last=last, born=born, valid=valid,
                 dirty=dirty_arr), evicted_dirty
+
+
+def _flat_target(state: TagState, array_idx, set_idx, way) -> jnp.ndarray:
+    """Flat ``(array, set, way)`` entry index of each request."""
+    _, n_sets, n_ways = state["tags"].shape
+    return (array_idx * n_sets + set_idx) * n_ways + way
+
+
+def _fill_sectored(state: TagState, array_idx, set_idx, way, addr, now,
+                   mask, sect) -> Tuple[TagState, jnp.ndarray]:
+    """``fill`` of clean sectored lines, in request order.
+
+    A request whose target way holds its line at that moment ORs its
+    sectors in; any other replaces the way. So each target ends holding
+    the line of its last request, with the sectors of the trailing run
+    of requests for that line (and the old ones, where no request
+    replaced the line the way held). Every request computes its
+    target's final entry, so duplicate scatter targets write equal
+    values. The second result is the valid-sector mask of each dirty
+    line a request evicts (0 elsewhere): only the old line can be
+    dirty, since the lines filled here are clean, so a request evicts
+    one where it is the first to replace the old line.
+    """
+    # the merge-or-replace decisions and the sector masks are the
+    # sector scope; the writes of the other fields are any fill's
+    with jax.named_scope(SECTOR_SCOPE):
+        R = addr.shape[0]
+        idx = jnp.arange(R, dtype=jnp.int32)
+        flat = _flat_target(state, array_idx, set_idx, way)
+        same = ((flat[:, None] == flat[None, :])
+                & mask[:, None] & mask[None, :])
+        last_req = jnp.max(jnp.where(same, idx[None, :], -1), axis=1)
+        line = addr[jnp.maximum(last_req, 0)]
+        boundary = jnp.max(jnp.where(same & (addr[None, :] != line[:, None]),
+                                     idx[None, :], -1), axis=1)
+        run = same & (idx[None, :] > boundary[:, None])
+        old_tag, old_valid, old_dirty, old_born, old_sect = (
+            state[k][array_idx, set_idx, way]
+            for k in ("tags", "valid", "dirty", "born", "sect"))
+        held = old_valid & (old_tag == line) & (boundary < 0)
+        new_sect = (_or_reduce(jnp.where(run, sect[None, :], 0), 1)
+                    | jnp.where(held, old_sect, 0))
+        before = same & (idx[None, :] < idx[:, None])
+        keeps_old = old_valid & (old_tag == addr)
+        evicted = (mask & ~keeps_old & old_valid & old_dirty
+                   & ~jnp.any(before & ~keeps_old[None, :], axis=1))
+        evicted_sect = jnp.where(
+            evicted,
+            old_sect | _or_reduce(jnp.where(before, sect[None, :], 0), 1), 0)
+        a = _drop_unmasked(state, array_idx, mask)
+        sect_arr = state["sect"].at[a, set_idx, way].set(new_sect,
+                                                         mode="drop")
+    at = lambda k: state[k].at[a, set_idx, way]
+    out = dict(state,
+               tags=at("tags").set(line, mode="drop"),
+               valid=at("valid").set(True, mode="drop"),
+               last=at("last").max(now, mode="drop"),
+               born=at("born").set(jnp.where(held, old_born, now),
+                                   mode="drop"),
+               dirty=at("dirty").set(held & old_dirty, mode="drop"),
+               sect=sect_arr)
+    return out, evicted_sect
 
 
 def _rows(state: TagState, x) -> jnp.ndarray:
@@ -252,13 +363,14 @@ def touch_rows(state: TagState, set_idx, way, now, mask, *,
 
 
 def fill_rows(state: TagState, set_idx, way, addr, now, mask, *,
-              dirty=None) -> Tuple[TagState, jnp.ndarray]:
+              dirty=None, sect=None) -> Tuple[TagState, jnp.ndarray]:
     """``fill`` where slot ``j`` of row ``a`` targets array ``a``.
 
     Fields are ``(n_arrays, k)``, or their flat array-major
     ``(n_arrays * k,)`` form; ``evicted_dirty`` comes back in ``mask``'s
     shape, read from the state before any slot writes. See the row form
-    in the module docstring.
+    in the module docstring. With ``sect``, see
+    :func:`_fill_rows_sectored`.
     """
     shape = jnp.shape(mask)
     set_idx, way, addr, mask = (_rows(state, x)
@@ -266,6 +378,11 @@ def fill_rows(state: TagState, set_idx, way, addr, now, mask, *,
     new_dirty = (_rows(state, dirty) if dirty is not None
                  else jnp.zeros(addr.shape, bool))
     rows = jnp.arange(addr.shape[0], dtype=jnp.int32)[:, None]
+    if sect is not None:
+        out, evicted = _fill_rows_sectored(state, rows, set_idx, way, addr,
+                                           now, mask, new_dirty,
+                                           _rows(state, sect))
+        return out, evicted.reshape(shape)
     evicted_dirty = (mask & state["valid"][rows, set_idx, way]
                      & state["dirty"][rows, set_idx, way])
     hits = _slot_targets(state, set_idx, way, mask)
@@ -279,6 +396,58 @@ def fill_rows(state: TagState, set_idx, way, addr, now, mask, *,
         dirty_arr = jnp.where(hit, new_dirty[:, j, None, None], dirty_arr)
     return dict(state, tags=tags, last=last, born=born, valid=valid,
                 dirty=dirty_arr), evicted_dirty.reshape(shape)
+
+
+def _fill_rows_sectored(state: TagState, rows, set_idx, way, addr, now,
+                        mask, new_dirty, sect):
+    """``fill_rows`` of sectored lines, in slot order.
+
+    Slot ``j`` sees its target as the slots before it left it: a way
+    holding the slot's line takes the OR of the sectors and dirty bits,
+    any other is replaced. What each slot finds there is worked out on
+    the ``(n_arrays, k)`` request fields, from the state before any
+    slot writes and the earlier slots of its row; the state is then
+    rewritten by the row form's in-order selects. The second result is
+    the valid-sector mask of each dirty line a slot evicts (0
+    elsewhere). The sector scope holds what each slot finds and the
+    sector masks' selects; the other fields' selects are any fill's.
+    """
+    with jax.named_scope(SECTOR_SCOPE):
+        k = addr.shape[1]
+        n_ways = state["tags"].shape[2]
+        flat = set_idx * n_ways + way
+        cur = [state[f][rows, set_idx, way]
+               for f in ("tags", "valid", "dirty", "sect")]
+        after, merged, evicted = [], [], []
+        for j in range(k):
+            tag, valid, dirt, have = (c[:, j] for c in cur)
+            for i in range(j):
+                same = mask[:, i] & (flat[:, i] == flat[:, j])
+                tag, valid, dirt, have = (
+                    jnp.where(same, new, old) for new, old in
+                    zip((addr[:, i], True) + after[i], (tag, valid, dirt,
+                                                        have)))
+            merge = valid & (tag == addr[:, j])
+            merged.append(merge)
+            after.append((jnp.where(merge, dirt | new_dirty[:, j],
+                                    new_dirty[:, j]),
+                          jnp.where(merge, have | sect[:, j], sect[:, j])))
+            evicted.append(jnp.where(mask[:, j] & ~merge & valid & dirt,
+                                     have, 0))
+    hits = _slot_targets(state, set_idx, way, mask)
+    tags, valid, last, born, dirty_arr, sect_arr = (
+        state[f] for f in ("tags", "valid", "last", "born", "dirty", "sect"))
+    for j, hit in enumerate(hits):
+        tags = jnp.where(hit, addr[:, j, None, None], tags)
+        valid = valid | hit
+        last = jnp.where(hit, jnp.maximum(last, now), last)
+        born = jnp.where(hit & ~merged[j][:, None, None], now, born)
+        dirty_arr = jnp.where(hit, after[j][0][:, None, None], dirty_arr)
+        with jax.named_scope(SECTOR_SCOPE):
+            sect_arr = jnp.where(hit, after[j][1][:, None, None], sect_arr)
+    return (dict(state, tags=tags, last=last, born=born, valid=valid,
+                 dirty=dirty_arr, sect=sect_arr),
+            jnp.stack(evicted, axis=1))
 
 
 def dead_victim(state: TagState, array_idx: jnp.ndarray,

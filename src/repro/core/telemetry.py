@@ -154,6 +154,13 @@ SIM_COUNTERS: Tuple[Counter, ...] = (
             cumulative=False),
     Counter("lat_hist", "loads", "bucket", "lat_hist",
             "log2-bucketed L1-complete latency histogram"),
+    # sectored geometries only (sectors_per_line > 1)
+    Counter("l1_sector_misses", "requests", "scalar", "l1_sector_misses",
+            "own-L1 tag hits lacking a requested sector"),
+    Counter("l2_sector_misses", "requests", "scalar", "l2_sector_misses",
+            "L2 tag hits lacking a needed sector"),
+    Counter("dram_sectors", "sectors", "scalar", "dram_sectors",
+            "sectors fetched from DRAM"),
 )
 
 #: Serving-engine counters, derived from the per-sub-round emission

@@ -180,8 +180,8 @@ class SimTimeline(_TimelineBase):
                 leaf = snaps["noc"][c.field[len("noc."):]]
             elif c.field in snaps["stats"]:
                 leaf = snaps["stats"][c.field]
-            else:            # lat_hist with histograms off
-                continue
+            else:   # lat_hist with histograms off; sector counters
+                continue   # of whole-line geometries
             cumulative[c.name] = _widen(leaf)
         return cls(window=telemetry.window, rounds=rounds,
                    cumulative=cumulative, meta=dict(meta or {}))
