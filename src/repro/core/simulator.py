@@ -346,9 +346,9 @@ def _l1_state(geom, policies: Sequence[ArchPolicy]) -> tagarray.TagState:
                                    sectors=geom.sectors_per_line)
 
 
-def _l2_state(geom) -> tagarray.TagState:
-    return tagarray.init_tag_state(geom.l2_parts, geom.l2_sets, geom.l2_ways,
-                                   sectors=geom.sectors_per_line)
+def _l2_state(geom) -> jnp.ndarray:
+    return tagarray.init_l2_state(geom.l2_parts, geom.l2_sets, geom.l2_ways,
+                                  sectors=geom.sectors_per_line)
 
 
 def _noc_state(geom, models: Sequence[NocModel]):
@@ -432,15 +432,14 @@ def _round(policy: ArchPolicy, nocs: Sequence[NocModel], noc_idx,
     with jax.named_scope(_L2):
         l2_part = (addr % geom.l2_parts).astype(jnp.int32)
         l2_set = ((addr // geom.l2_parts) % geom.l2_sets).astype(jnp.int32)
-        l2_hit, l2_way, _ = tagarray.probe(l2, l2_part, l2_set, addr)
+        l2_hit, l2_way, l2_rec, l2_row = tagarray.probe_l2(
+            l2, l2_part, l2_set, addr, geom.l2_ways)
+        l2_tag_hit, fetch = l2_hit, None
         if sectored:
             with jax.named_scope(tagarray.SECTOR_SCOPE):
-                l2_tag_hit = l2_hit
-                fetch = jnp.where(
-                    l2_tag_hit,
-                    out.need & ~tagarray.sectors_at(l2, l2_part, l2_set,
-                                                    l2_way),
-                    out.need)
+                fetch = jnp.where(l2_tag_hit,
+                                  out.need & ~l2_rec[:, tagarray.L2_SECT],
+                                  out.need)
                 l2_hit = fetch == 0
         l2_rank, l2_size = group_rank(l2_part, go_l2, geom.l2_parts)
         l2_time = (geom.lat_l2 + l2_rank.astype(jnp.float32) * geom.svc_l2
@@ -448,19 +447,14 @@ def _round(policy: ArchPolicy, nocs: Sequence[NocModel], noc_idx,
         occupancy = jnp.maximum(
             occupancy,
             jnp.where(go_l2, l2_size.astype(jnp.float32) * geom.svc_l2, 0.0))
+        l2 = tagarray.update_l2(l2, l2_part, l2_set, l2_way, l2_row, addr, t,
+                                go_l2 & l2_tag_hit, go_l2 & ~l2_tag_hit,
+                                fetch=fetch, need=out.need)
         if sectored:
-            l2 = tagarray.touch(l2, l2_part, l2_set, l2_way, t,
-                                go_l2 & l2_tag_hit, sect=fetch)
-            l2, _ = tagarray.fill(l2, l2_part, l2_set, l2_way, addr, t,
-                                  go_l2 & ~l2_tag_hit, sect=out.need)
             noc_flits = noc_flits + jnp.sum(jnp.where(
                 go_l2, jax.lax.population_count(out.need), 0)
             ) * sector_flits
         else:
-            l2 = tagarray.touch(l2, l2_part, l2_set, l2_way, t,
-                                go_l2 & l2_hit)
-            l2, _ = tagarray.fill(l2, l2_part, l2_set, l2_way, addr, t,
-                                  go_l2 & ~l2_hit)
             noc_flits = noc_flits + jnp.sum(go_l2) * geom.flits_per_line
 
     # ---- L1 fill on L2 return (and on remote fetch: replicate locally) ----

@@ -47,7 +47,10 @@ parked no-op landing after a genuine update to array 0 / set 0 / way 0
 would revert it — e.g. a core-0 fill undone, a dirty bit lost, a missed
 write-back.) Within the masked-*in* requests, duplicate
 (array, set, way) targets still resolve last-writer-wins, matching a
-single-ported fill path.
+single-ported fill path. XLA leaves the winner among duplicate scatter
+indices unspecified on the TPU, so where the order matters the caller
+resolves it first (the sectored fills, the packed L2 below) or avoids
+the scatter (the row form).
 
 Sectors: a line's tag covers ``sectors`` sector bits. ``sectors_at``
 reads the mask of a way ``probe``/``probe_many`` found. With ``sect``
@@ -70,6 +73,25 @@ every field (a hypothesis test asserts this). A TPU applies a
 scatter's updates one after another (about 80 ns each on a v5e at
 paper geometry); the selects fuse into elementwise passes over the
 state.
+
+Packed L2 state: the shared L2's targets span partitions, so the row
+form does not apply, and a TPU's scatter costs per update, whatever
+the bytes one update writes. So the L2 is carried as one int32 array
+``(n_parts, n_sets, F * n_ways)``, field ``f`` of way ``w`` at
+``f * n_ways + w``, of the fields it reads: ``tags``, ``last``, ``valid``
+(0/1) and, with sectors, ``sect`` (``init_l2_state``; it is LRU and
+installs clean lines, so ``born`` and ``dirty`` are left out). A set
+is one row, which the TPU keeps in its natural layout for both the
+probe's row gather and the write, so the state is never relaid out.
+``probe_l2`` reads one row per request. ``update_l2`` applies a
+round's touches, then its fills, each in request order, as ``touch``
+and ``fill`` on the per-field state would (tests assert it): every
+request works out the final record of each way of its set on an
+``R x R x n_ways`` same-target compare, and the last request of each
+set writes the set's row; the others go out of bounds, each to an
+index of its own. So one scatter with unique
+indices writes every field, and its result cannot depend on the order
+a device applies updates in.
 """
 from __future__ import annotations
 
@@ -448,6 +470,108 @@ def _fill_rows_sectored(state: TagState, rows, set_idx, way, addr, now,
     return (dict(state, tags=tags, last=last, born=born, valid=valid,
                  dirty=dirty_arr, sect=sect_arr),
             jnp.stack(evicted, axis=1))
+
+
+# ---------------------------------------------------------------------------
+# Packed L2 state (see module docstring)
+# ---------------------------------------------------------------------------
+#: Fields of a packed L2 set row; ``L2_SECT`` only where ``sectors > 1``.
+L2_TAG, L2_LAST, L2_VALID, L2_SECT = range(4)
+
+
+def init_l2_state(n_parts: int, n_sets: int, n_ways: int, *,
+                  sectors: int = 1) -> jnp.ndarray:
+    """Empty packed L2 state: ``(n_parts, n_sets, F * n_ways)`` int32,
+    field ``f`` of way ``w`` at ``[..., f * n_ways + w]`` (``F`` = 3, or
+    4 with sectors)."""
+    empty = [0, -1, 0, 0][:4 if sectors > 1 else 3]   # tag, last, valid, sect
+    return jnp.broadcast_to(jnp.repeat(jnp.asarray(empty, jnp.int32), n_ways),
+                            (n_parts, n_sets, n_ways * len(empty)))
+
+
+def _l2_fields(row: jnp.ndarray, n_ways: int) -> list:
+    """The ``(R, n_ways)`` field arrays of ``(R, F * n_ways)`` set rows."""
+    return [row[:, f * n_ways:(f + 1) * n_ways]
+            for f in range(row.shape[1] // n_ways)]
+
+
+def probe_l2(state: jnp.ndarray, part, set_idx, addr, n_ways: int
+             ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """LRU lookup of one (partition, set) per request, as ``probe``.
+
+    Returns (hit, way, record, row): way is the hit way or the LRU
+    victim, row the ``(R, F * n_ways)`` set the one row gather read, and
+    record the ``(R, F)`` fields of that way."""
+    row = state[part, set_idx]
+    fields = _l2_fields(row, n_ways)
+    valid = fields[L2_VALID] != 0
+    match = (fields[L2_TAG] == addr[:, None]) & valid
+    hit = match.any(axis=-1)
+    victim = jnp.argmin(jnp.where(valid, fields[L2_LAST],
+                                  jnp.iinfo(jnp.int32).min), axis=-1)
+    way = jnp.where(hit, jnp.argmax(match, axis=-1), victim)
+    at_way = jnp.arange(n_ways, dtype=way.dtype) == way[:, None]
+    record = jnp.stack([jnp.sum(jnp.where(at_way, f, 0), axis=1)
+                        for f in fields], axis=1)
+    return hit, way, record, row
+
+
+def update_l2(state: jnp.ndarray, part, set_idx, way, row, addr, now,
+              touch, fill, *, fetch=None, need=None) -> jnp.ndarray:
+    """``touch`` of the ``touch`` requests, then clean ``fill`` of the
+    ``fill`` requests, each in request order, in one scatter.
+
+    ``row`` is each request's set as ``probe_l2`` read it. Each request
+    works out the final record of every way of its set from the
+    requests that target that way: ``last`` the max with ``now``; where
+    a fill targets it, the last fill's line, valid. With sectors, the
+    touches OR their ``fetch`` sectors in, then the fills merge or
+    replace as :func:`_fill_sectored` does, with their ``need``
+    sectors. The last request of each set writes the set's row; the
+    others go out of bounds (the scatter-mask convention), each to an
+    index of its own, so the scatter's indices are unique and its order
+    cannot matter.
+    """
+    n_parts, n_sets, width = state.shape
+    n_fields = L2_SECT + 1 if fetch is not None else L2_SECT
+    n_ways = width // n_fields
+    tag, last, valid, *sect = _l2_fields(row, n_ways)
+    idx = jnp.arange(addr.shape[0], dtype=jnp.int32)
+    active = touch | fill
+    set_key = part * n_sets + set_idx
+    same_set = (set_key[:, None] == set_key[None, :]) & active[None, :]
+    # (i, j, w): request j targets way w of request i's set.
+    at = same_set[:, :, None] & (way[None, :, None]
+                                 == jnp.arange(n_ways)[None, None, :])
+    j = idx[None, :, None]
+    fills = at & fill[None, :, None]
+    last_fill = jnp.max(jnp.where(fills, j, -1), axis=1)          # (R, W)
+    filled = last_fill >= 0
+    line = addr[jnp.maximum(last_fill, 0)]
+    new = [jnp.where(filled, line, tag),
+           jnp.where(at.any(axis=1), jnp.maximum(last, now), last),
+           jnp.where(filled, 1, valid)]
+    if fetch is not None:
+        with jax.named_scope(SECTOR_SCOPE):
+            touched = sect[0] | _or_reduce(
+                jnp.where(at & touch[None, :, None], fetch[None, :, None],
+                          0), 1)
+            boundary = jnp.max(jnp.where(
+                fills & (addr[None, :, None] != line[:, None, :]), j, -1),
+                axis=1)
+            run = fills & (j > boundary[:, None, :])
+            held = (valid != 0) & (tag == line) & (boundary < 0)
+            new.append(jnp.where(
+                filled,
+                _or_reduce(jnp.where(run, need[None, :, None], 0), 1)
+                | jnp.where(held, touched, 0),
+                touched))
+    writes = active & (jnp.max(jnp.where(same_set, idx[None, :], -1),
+                               axis=1) == idx)
+    p = jnp.where(writes, part, n_parts + idx)
+    return state.at[p, set_idx].set(
+        jnp.concatenate(new, axis=1).astype(jnp.int32), mode="drop",
+        unique_indices=True)
 
 
 def dead_victim(state: TagState, array_idx: jnp.ndarray,

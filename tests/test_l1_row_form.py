@@ -1,15 +1,17 @@
 """The round's L1 tag-state updates lower to the row form (masked
 selects) wherever a policy writes only its requester's own L1, and to
 scatters where the target is another array (``decoupled``'s home
-cache)."""
+cache). The L2 stage writes its packed state with one scatter a round,
+on whole-line and sectored geometries."""
 import dataclasses
 import re
 
+import numpy as np
 import pytest
 
 from repro.core import APPS, PAPER_GEOMETRY, SweepGrid, make_trace, sweep
 from repro.core import simulator
-from repro.core.geometry import split_geometry
+from repro.core.geometry import GpuGeometry, split_geometry
 
 #: Each StableHLO scatter's operand type (the first in its signature).
 SCATTER = re.compile(r'"stablehlo\.scatter"\(.*?\}\) : \((tensor<[^>]*>)',
@@ -52,9 +54,35 @@ def test_l1_updates_scatter_only_where_the_target_is_another_array(
     g = PAPER_GEOMETRY
     text = _lowered(arch, batched)
     l1 = _scatter_operands(text, (g.n_cores, g.l1_sets, g.l1_ways))
-    l2 = _scatter_operands(text, (g.l2_parts, g.l2_sets, g.l2_ways))
-    assert l2, "the L2 stage keeps its scatters"
+    assert _l2_scatters(text, g) == 1
     if arch == "decoupled":
         assert l1, "the home-cache fill keeps the scatter form"
     else:
         assert l1 == [], l1
+
+
+def _l2_scatters(text, g):
+    """Scatters on the L2 state: on the packed ``(P, S, F * W)`` state,
+    and none left on a per-field ``(P, S, W)`` one."""
+    fields = 4 if g.sectors_per_line > 1 else 3
+    assert _scatter_operands(text, (g.l2_parts, g.l2_sets, g.l2_ways)) == []
+    return len(_scatter_operands(
+        text, (g.l2_parts, g.l2_sets, fields * g.l2_ways)))
+
+
+def test_sectored_l2_takes_one_scatter_a_round():
+    """The full GH100 geometry, 32-byte sectors: the touches' and fills'
+    sector merges ride in the same one scatter."""
+    g = GpuGeometry(n_cores=144, cluster_size=18, l1_sets=32, l1_ways=64,
+                    l1_banks=4, l2_parts=48, l2_sets=640, l2_ways=16,
+                    sectors_per_line=4)
+    rng = np.random.default_rng(0)
+    shape = (4, g.n_cores, 4)
+    trace = simulator.Trace(rng.integers(0, 1 << 16, shape).astype(np.int32),
+                            rng.random(shape) < 0.1, 6.0,
+                            sect=rng.integers(1, 16, shape))
+    structure, scalars = split_geometry(g)
+    pa = simulator._point_arrays(simulator._trace_arrays(trace, 4), scalars)
+    text = simulator._simulate.lower(
+        ("ata",), ("ideal",), pa, structure, 1, "lax", None).as_text()
+    assert _l2_scatters(text, g) == 1

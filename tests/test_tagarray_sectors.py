@@ -109,7 +109,7 @@ def test_fill_rows_or_merges_under_vmap(seed):
 
 @pytest.mark.parametrize("seed", range(6))
 def test_scatter_fill_or_merges_in_request_order(seed):
-    """The scatter form (the L2's), clean fills to any array in request
+    """The scatter form, clean fills to any array in request
     order, duplicate targets resolved before the scatter."""
     rng = np.random.default_rng(200 + seed)
     state = _state(rng)

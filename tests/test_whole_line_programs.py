@@ -1,19 +1,19 @@
 """Whole-line geometries (``sectors_per_line = 1``, the default) compile
-exactly the programs they compiled before sectored lines were modelled:
-the optimised HLO of a paper-geometry sweep point, with its metadata
-(op names, source locations) left out, hashes as it did then. So no
-cell of the paper machine can move."""
+exactly the programs recorded here: the optimised HLO of a
+paper-geometry sweep point, with its metadata (op names, source
+locations) left out, hashes as recorded. So nothing meant for
+sectored lines alone can move a cell of the paper machine."""
 import hashlib
 import re
 
 import numpy as np
 import pytest
 
-#: sha256 of each normalised executable, recorded before the sector
-#: model was added (CPU backend, one device).
+#: sha256 of each normalised executable (CPU backend, one device),
+#: recorded with the packed L2 state and its one scatter a round.
 WANT = {
-    "private": "0520744cb865fe15b43abcc4690145c3cf8b34dd2319aef34f4dfb17092b3971",
-    "ata": "4fac71fbe9aae9abf7d45686df2329dbc32b9f87501f1670e378055a54428bcb",
+    "private": "b3f3a2d25915ccfd2ac0ad4b7dd5a9ad497830b7d802537378e282851048f817",
+    "ata": "aec17973ae1211c95d27d65d345289dc2c60c075ae22ded9dff70e89439f64ca",
 }
 
 _METADATA = re.compile(r", metadata=\{[^}]*\}")
